@@ -187,6 +187,8 @@ def main(argv=None) -> int:
         print(f"unknown sections {unknown}; available: {list(SECTIONS)}",
               file=sys.stderr)
         return 2
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     results = {}
     for i, name in enumerate(names):
         if i:

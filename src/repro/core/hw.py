@@ -9,8 +9,9 @@ live at three tiers:
   tier 2 (lane):   VPU (8 sublanes x 128 lanes) and MXU (128x128) tiling.
 
 ``detect()`` queries ``jax.devices()`` at runtime (the paper's "evaluated at
-runtime based on the hardware properties") and falls back to a registry of
-known parts.  A ``VortexParams`` model is kept as well so the paper's own
+runtime based on the hardware properties") and looks the chip up by its
+``device_kind`` in a registry of known parts; an unknown TPU is an error.
+A ``VortexParams`` model is kept as well so the paper's own
 450-configuration sweep can be reproduced exactly (benchmarks/fig2_sweep).
 """
 
@@ -24,6 +25,7 @@ __all__ = [
     "TpuParams",
     "VortexParams",
     "TPU_REGISTRY",
+    "TPU_DEVICE_KINDS",
     "detect",
     "hardware_parallelism",
 ]
@@ -34,8 +36,9 @@ class TpuParams:
     """Micro-architecture parameters of one accelerator chip + its mesh.
 
     Bandwidths are bytes/s, compute is FLOP/s.  ``vmem_budget_bytes`` is the
-    fraction of VMEM a single Pallas program may claim (leave headroom for
-    double buffering and the compiler's own scratch).
+    VMEM a single Pallas program may claim: the planners size blocks
+    (double buffers and f32 temporaries included) against it, and every
+    planned kernel declares it to the compiler as ``vmem_limit_bytes``.
     """
 
     name: str
@@ -142,24 +145,33 @@ TPU_REGISTRY: dict[str, TpuParams] = {
 }
 
 
+#: ``jax.Device.device_kind`` of each registered TPU -> its registry entry
+TPU_DEVICE_KINDS: dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v4": "tpu_v4",
+}
+
+
 def detect(num_chips: Optional[int] = None) -> TpuParams:
     """Runtime hardware introspection (paper §2: "evaluated at runtime
     based on the hardware properties").
 
-    Maps ``jax.devices()`` onto the registry; unknown TPU kinds fall back to
-    v5e parameters, non-TPU platforms to ``cpu_sim``.
+    Maps ``jax.devices()[0].device_kind`` onto the registry through
+    ``TPU_DEVICE_KINDS``; a TPU kind not listed there raises
+    ``ValueError`` (its parameters would be a guess).  Non-TPU platforms
+    get the ``cpu_sim`` stand-in.
     """
     import jax
 
     devs = jax.devices()
     n = num_chips if num_chips is not None else len(devs)
-    plat = devs[0].platform
-    if plat == "tpu":
-        kind = getattr(devs[0], "device_kind", "").lower()
-        if "v4" in kind:
-            return TPU_REGISTRY["tpu_v4"].with_chips(n)
-        return TPU_REGISTRY["tpu_v5e"].with_chips(n)
-    return TPU_REGISTRY["cpu_sim"].with_chips(n)
+    if devs[0].platform != "tpu":
+        return TPU_REGISTRY["cpu_sim"].with_chips(n)
+    kind = devs[0].device_kind
+    if kind not in TPU_DEVICE_KINDS:
+        raise ValueError(f"unknown TPU device_kind {kind!r}: add its "
+                         f"parameters to TPU_REGISTRY and TPU_DEVICE_KINDS")
+    return TPU_REGISTRY[TPU_DEVICE_KINDS[kind]].with_chips(n)
 
 
 def hardware_parallelism(hw: TpuParams) -> int:

@@ -136,7 +136,7 @@ def plan_vector_blocks(
     """
     q = _lane_quantum(hw)
     hp_programs = hw.cores_per_chip  # concurrently resident programs
-    vmem_cap = hw.vmem_budget_bytes // (n_streams * w.dtype_bytes)
+    vmem_cap = hw.vmem_budget_bytes // (2 * n_streams * w.dtype_bytes)
     vmem_cap = max(q, (vmem_cap // q) * q)
 
     if policy is MappingPolicy.NAIVE:
@@ -186,7 +186,8 @@ def vector_plan_for_block(
         sequential_rounds=rounds,
         utilization=util,
         regime=classify_regime(lws_eff, ceil_div(w.gws, q), hp_programs),
-        vmem_bytes=block * w.dtype_bytes * n_streams,
+        # every stream's block is double-buffered by the Pallas pipeline
+        vmem_bytes=2 * block * w.dtype_bytes * n_streams,
     )
 
 
@@ -200,6 +201,14 @@ class MatmulPlan:
     utilization: float               # MXU tile occupancy incl. padding
     vmem_bytes: int
     regime: Regime
+
+
+def _matmul_vmem_bytes(bm: int, bn: int, bk: int, dtype_bytes: int) -> int:
+    """VMEM one ``kernels.matmul`` program claims: double-buffered A, B
+    and C blocks, plus the f32 accumulator scratch, the f32 casts of the
+    A and B blocks and the f32 product tile the kernel body creates."""
+    return (2 * (bm * bk + bk * bn + bm * bn) * dtype_bytes
+            + (bm * bk + bk * bn + 2 * bm * bn) * 4)
 
 
 def plan_matmul_blocks(
@@ -216,14 +225,13 @@ def plan_matmul_blocks(
     reduction is looped over ``bk`` chunks inside the program (temporal).
     AUTO solves Eq. 1 over output tiles: tiles_total = (m/128)(n/128),
     per-program tiles = tiles_total / cores, then factorizes into bm x bn
-    favouring square-ish blocks and clamps by VMEM
-    (bm*bk + bk*bn + bm*bn elements resident).
+    favouring square-ish blocks and clamps by VMEM (``_matmul_vmem_bytes``).
     """
     t = hw.mxu_dim
     mt, nt = ceil_div(m, t), ceil_div(n, t)
 
     def vmem(bm: int, bn: int, bk: int) -> int:
-        return (bm * bk + bk * bn + bm * bn * 2) * dtype_bytes
+        return _matmul_vmem_bytes(bm, bn, bk, dtype_bytes)
 
     if policy is MappingPolicy.NAIVE:
         bm, bn = min(t, round_up(m, 8)), min(t, round_up(n, t))
@@ -274,7 +282,7 @@ def matmul_plan_for_blocks(
     grid = (ceil_div(m, bm), ceil_div(n, bn), ceil_div(k, bk))
     padded = grid[0] * bm * grid[1] * bn
     util = (m * n) / padded
-    vmem = (bm * bk + bk * bn + bm * bn * 2) * dtype_bytes
+    vmem = _matmul_vmem_bytes(bm, bn, bk, dtype_bytes)
     lws_tiles = (bm // min(bm, t)) * max(bn // t, 1)
     return MatmulPlan(
         policy=policy, bm=bm, bn=bn, bk=bk, grid=grid,
@@ -292,6 +300,17 @@ class AttentionPlan:
     vmem_bytes: int
 
 
+def _attention_vmem_bytes(bq: int, bk: int, head_dim: int,
+                         dtype_bytes: int) -> int:
+    """VMEM one ``kernels.flash_attention`` program claims (head_dim
+    lane-padded to 128): double-buffered q, k, v and o blocks, the f32
+    running stats and accumulator, the f32 casts of q, k and v, and the
+    f32 score and probability tiles."""
+    hd = max(head_dim, 128)
+    return (2 * (2 * bq * hd + 2 * bk * hd) * dtype_bytes
+            + (2 * bq * hd + 2 * bk * hd + 2 * bq * bk + 2 * bq) * 4)
+
+
 def plan_attention_blocks(
     seq_q: int,
     seq_k: int,
@@ -302,11 +321,8 @@ def plan_attention_blocks(
 ) -> AttentionPlan:
     """Flash-attention tiling: block_q rows resident, loop seq_k in block_k
     chunks (the temporal ``lws`` loop)."""
-    hd = max(head_dim, 128)
-
     def vmem(bq: int, bk: int) -> int:
-        # q, o, running stats + k/v tiles + score tile
-        return (bq * hd * 3 + 2 * bk * hd + bq * bk) * dtype_bytes * 2
+        return _attention_vmem_bytes(bq, bk, head_dim, dtype_bytes)
 
     if policy is MappingPolicy.NAIVE:
         bq, bk = 8, 128
@@ -337,10 +353,9 @@ def attention_plan_for_blocks(
     """Build the full ``AttentionPlan`` from the (block_q, block_k)
     decision — shared by ``plan_attention_blocks`` and the tuner."""
     del hw  # legalization is shape-driven; kept for signature symmetry
-    hd = max(head_dim, 128)
     bq = min(max(8, bq // 8 * 8), round_up(seq_q, 8))
     bk = min(max(128, bk // 128 * 128), round_up(seq_k, 128))
-    vmem = (bq * hd * 3 + 2 * bk * hd + bq * bk) * dtype_bytes * 2
+    vmem = _attention_vmem_bytes(bq, bk, head_dim, dtype_bytes)
     return AttentionPlan(
         policy=policy, block_q=bq, block_k=bk,
         grid_q=ceil_div(seq_q, bq), vmem_bytes=vmem,

@@ -215,8 +215,6 @@ def roofline_from_compiled(
 ) -> RooflineReport:
     """Build the three-term report from a ``jax.stages.Compiled``."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()

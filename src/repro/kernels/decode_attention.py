@@ -93,24 +93,30 @@ def decode_attention_pallas(
     sp = round_up(s, block_s)
     kp = jnp.pad(k_cache, ((0, sp - s), (0, 0))) if sp != s else k_cache
     vp = jnp.pad(v_cache, ((0, sp - s), (0, 0))) if sp != s else v_cache
+    # the length is a scalar-prefetch (SMEM) operand: a (1,) VMEM block
+    # over it is refused by the TPU tiling rules once vmap batches it
     clen = jnp.asarray(s if cache_len is None else cache_len,
                        jnp.int32).reshape(1)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale),
         out_shape=jax.ShapeDtypeStruct((1, d), q.dtype),
-        grid=(sp // block_s,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((block_s, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_s, d), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda i: (0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(sp // block_s,),
+            in_specs=[
+                pl.BlockSpec((1, d), lambda i, n: (0, 0)),
+                pl.BlockSpec((block_s, d), lambda i, n: (i, 0)),
+                pl.BlockSpec((block_s, d), lambda i, n: (i, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, d), lambda i, n: (0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((1,), jnp.float32),
+                pltpu.VMEM((1,), jnp.float32),
+                pltpu.VMEM((1, d), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=hw.vmem_budget_bytes),
         interpret=interpret,
     )(clen, q.reshape(1, d), kp, vp)
     return out[0]

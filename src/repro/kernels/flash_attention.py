@@ -23,13 +23,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import TpuParams, round_up
 from repro.core.mapper import AttentionPlan, MappingPolicy, plan_attention_blocks
-from repro.core.compat import tpu_compiler_params
 
 _NEG_INF = float("-inf")
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                  *, scale: float, causal: bool, q_offset: int):
+def _flash_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+                  *, scale: float, causal: bool):
     qi = pl.program_id(0)
     ki = pl.program_id(1)
     bq = q_ref.shape[0]
@@ -46,7 +45,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (bq, bk)
 
     if causal:
-        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + q_offset
+        q_pos = (qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+                 + off_ref[0])
         k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
 
@@ -78,9 +78,16 @@ def flash_attention_pallas(
     scale: float | None = None,
     policy: MappingPolicy = MappingPolicy.AUTO,
     plan: AttentionPlan | None = None,
+    q_offset=None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Single-head attention: q (sq, d), k/v (skv, d).  Heads/batch vmap."""
+    """Single-head attention: q (sq, d), k/v (skv, d).  Heads/batch vmap.
+
+    ``q_offset`` is the absolute position of query row 0 (an int or a
+    traced scalar; ``None`` aligns the queries to the END of the keys,
+    ``skv - sq``, the cached-prefix case).  It is a scalar-prefetch
+    operand, so a chunked prefill's traced start offset shares one
+    compiled kernel across chunks."""
     sq, d = q.shape
     skv = k.shape[0]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -89,33 +96,39 @@ def flash_attention_pallas(
                                      dtype_bytes=q.dtype.itemsize)
     bq, bk = min(plan.block_q, round_up(sq, 8)), min(plan.block_k, round_up(skv, 128))
     sqp, skvp = round_up(sq, bq), round_up(skv, bk)
-    q_offset = skv - sq  # causal alignment for cached prefixes
+    if q_offset is None:
+        q_offset = skv - sq  # causal alignment for cached prefixes
+    off = jnp.asarray(q_offset, jnp.int32).reshape(1)
     qp = jnp.pad(q, ((0, sqp - sq), (0, 0))) if sqp != sq else q
     kp = jnp.pad(k, ((0, skvp - skv), (0, 0))) if skvp != skv else k
     vp = jnp.pad(v, ((0, skvp - skv), (0, 0))) if skvp != skv else v
     if skvp != skv and not causal:
         raise ValueError("non-causal attention requires skv % block_k == 0")
 
-    kern = functools.partial(_flash_kernel, scale=scale, causal=causal or skvp != skv,
-                             q_offset=q_offset)
+    kern = functools.partial(_flash_kernel, scale=scale,
+                             causal=causal or skvp != skv)
     out = pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct((sqp, d), q.dtype),
-        grid=(sqp // bq, skvp // bk),
-        in_specs=[
-            pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bk, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bk, d), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(sqp // bq, skvp // bk),
+            in_specs=[
+                pl.BlockSpec((bq, d), lambda i, j, o: (i, 0)),
+                pl.BlockSpec((bk, d), lambda i, j, o: (j, 0)),
+                pl.BlockSpec((bk, d), lambda i, j, o: (j, 0)),
+            ],
+            out_specs=pl.BlockSpec((bq, d), lambda i, j, o: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bq,), jnp.float32),
+                pltpu.VMEM((bq,), jnp.float32),
+                pltpu.VMEM((bq, d), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=hw.vmem_budget_bytes,
         ),
         interpret=interpret,
-    )(qp, kp, vp)
+    )(off, qp, kp, vp)
     return out[:sq] if sqp != sq else out

@@ -21,7 +21,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import TpuParams, round_up
 from repro.core.mapper import MappingPolicy, resolve_lws
-from repro.core.compat import tpu_compiler_params
 
 
 def plan_node_block(n: int, f: int, hw: TpuParams, policy: MappingPolicy,
@@ -97,8 +96,9 @@ def gcn_aggregate_pallas(
         ],
         out_specs=pl.BlockSpec((block_n, f), lambda i, j: (i, 0)),
         scratch_shapes=[pltpu.VMEM((block_n, f), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=hw.vmem_budget_bytes,
         ),
         interpret=interpret,
     )(occ, a, x)

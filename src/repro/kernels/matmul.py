@@ -18,7 +18,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import TpuParams, round_up
 from repro.core.mapper import MappingPolicy, MatmulPlan, plan_matmul_blocks
-from repro.core.compat import tpu_compiler_params
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref):
@@ -73,8 +72,9 @@ def matmul_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=hw.vmem_budget_bytes,
         ),
         interpret=interpret,
     )(a, b)

@@ -20,7 +20,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import TpuParams, round_up
 from repro.core.mapper import MappingPolicy, resolve_lws
-from repro.core.compat import tpu_compiler_params
 
 _BIG = 3.4e38  # plain float: jnp constants would be captured as tracers
 
@@ -99,8 +98,9 @@ def nn_search_pallas(
                    pl.BlockSpec((block_q,), lambda i, j: (i,))),
         scratch_shapes=[pltpu.VMEM((block_q,), jnp.float32),
                         pltpu.VMEM((block_q,), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=hw.vmem_budget_bytes,
         ),
         interpret=interpret,
     )(qp, rp)

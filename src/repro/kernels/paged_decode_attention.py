@@ -184,7 +184,10 @@ def _sweep_page(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref, *,
                 page_block: int, ppb: int, scale: float):
     """One physical page's online-softmax update — the shared body of
     the fp32 and int8 kernels (which differ only in how ``k``/``v`` were
-    produced from their refs)."""
+    produced from their refs).  ``len_ref`` is the scalar-prefetched
+    (B,) cache-length vector in SMEM: a (1,)-block VMEM operand over it
+    is refused by the TPU tiling rules."""
+    clen = len_ref[pl.program_id(0)]
     si = pl.program_id(1)
     pi = pl.program_id(2)
 
@@ -199,7 +202,7 @@ def _sweep_page(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref, *,
                    preferred_element_type=jnp.float32)  # (G, R, pb)
     pos = (si * ppb + pi) * page_block \
         + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_block), 2)
-    s = jnp.where(pos < len_ref[0], s, _NEG_INF)
+    s = jnp.where(pos < clen, s, _NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -231,12 +234,12 @@ def _paged_decode_kernel_int8(tbl_ref, len_ref, q_ref, k_ref, v_ref,
                               ks_ref, vs_ref, o_ref,
                               m_ref, l_ref, acc_ref, *,
                               page_block: int, ppb: int, scale: float):
-    # the (1, G) scale rows rode the SAME scalar-prefetched flat-block
+    # the (1, 1, G) scale rows rode the SAME scalar-prefetched flat-block
     # index as the int8 pages; dequant is in-register, per page — the
     # f32 view never exists outside this grid step
     del tbl_ref
-    k = k_ref[0].astype(jnp.float32) * ks_ref[0][None, :, None]
-    v = v_ref[0].astype(jnp.float32) * vs_ref[0][None, :, None]
+    k = k_ref[0].astype(jnp.float32) * ks_ref[0, 0][None, :, None]
+    v = v_ref[0].astype(jnp.float32) * vs_ref[0, 0][None, :, None]
     _sweep_page(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
                 page_block=page_block, ppb=ppb, scale=scale)
 
@@ -246,7 +249,7 @@ def paged_decode_attention_pallas(
     k_cache: jax.Array,           # (B, T, G, D) — PHYSICAL block grid
     v_cache: jax.Array,
     tables: jax.Array,            # (B, nb) int32, -1 = unmapped
-    cache_len: jax.Array,         # (B,)
+    cache_len,                    # scalar or (B,)
     *,
     page_block: int,
     block_s: int,
@@ -258,9 +261,11 @@ def paged_decode_attention_pallas(
     """The fused kernel: grid (B, T/block_s, block_s/page_block), the
     scalar-prefetched flat-block table routing ONE physical page per
     innermost grid step straight into the online softmax — decode reads
-    paged KV with zero intermediate materialization.  With
-    ``k_scale``/``v_scale`` the caches hold int8 codes; the scales are
-    two extra (1, G) BlockSpec inputs riding the SAME prefetched table
+    paged KV with zero intermediate materialization.  The cache lengths
+    are the second scalar-prefetch operand.  With ``k_scale``/``v_scale``
+    the caches hold int8 codes; the scales are two extra (1, 1, G)
+    BlockSpec inputs over a (B*nb, 1, G) view (the last two block dims
+    whole, as the TPU tiling requires) riding the SAME prefetched table
     entry as their page, dequantized in-register inside the sweep.
 
     Example::
@@ -272,8 +277,10 @@ def paged_decode_attention_pallas(
     b, t = k_cache.shape[:2]
     g, r, d = q.shape[1:]
     pb = int(page_block)
-    assert t % pb == 0, (t, pb)
-    assert block_s % pb == 0 and block_s >= pb, (block_s, pb)
+    if t % pb or block_s % pb or block_s < pb:
+        raise ValueError(
+            f"fused paged decode needs whole pages: cache length {t} and "
+            f"block_s {block_s} must be multiples of page_block {pb}")
     scale = scale if scale is not None else d ** -0.5
     nb = t // pb
     ppb = min(block_s // pb, nb)
@@ -291,34 +298,34 @@ def paged_decode_attention_pallas(
     quant = k_scale is not None
 
     page_spec = pl.BlockSpec((1, pb, g, d),
-                             lambda bi, si, pi, tbl:
+                             lambda bi, si, pi, tbl, lens:
                              (tbl[bi, si * ppb + pi], 0, 0, 0))
-    scale_spec = pl.BlockSpec((1, g),
-                              lambda bi, si, pi, tbl:
-                              (tbl[bi, si * ppb + pi], 0))
+    scale_spec = pl.BlockSpec((1, 1, g),
+                              lambda bi, si, pi, tbl, lens:
+                              (tbl[bi, si * ppb + pi], 0, 0))
     in_specs = [
-        pl.BlockSpec((1,), lambda bi, si, pi, tbl: (bi,)),
         pl.BlockSpec((1, g, r, d),
-                     lambda bi, si, pi, tbl: (bi, 0, 0, 0)),
+                     lambda bi, si, pi, tbl, lens: (bi, 0, 0, 0)),
         page_spec,
         page_spec,
     ]
-    operands = [clen, q, blocks_k, blocks_v]
+    operands = [q, blocks_k, blocks_v]
     kernel = _paged_decode_kernel
     if quant:
         in_specs += [scale_spec, scale_spec]
-        operands += [k_scale.reshape(b * nb, g),
-                     v_scale.reshape(b * nb, g)]
+        operands += [k_scale.reshape(b * nb, 1, g),
+                     v_scale.reshape(b * nb, 1, g)]
         kernel = _paged_decode_kernel_int8
 
     out = pl.pallas_call(
         functools.partial(kernel, page_block=pb, ppb=ppb, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b, nsteps, ppb),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, g, r, d),
-                                   lambda bi, si, pi, tbl: (bi, 0, 0, 0)),
+                                   lambda bi, si, pi, tbl, lens:
+                                   (bi, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((g, r), jnp.float32),
                 pltpu.VMEM((g, r), jnp.float32),
@@ -327,7 +334,7 @@ def paged_decode_attention_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((b, g, r, d), q.dtype),
         interpret=interpret,
-    )(flat_block, *operands)
+    )(flat_block, clen, *operands)
     return out
 
 
@@ -347,24 +354,22 @@ def paged_decode_attention(
     use_pallas: bool = False,
     interpret: bool = False,
 ) -> jax.Array:
-    """Dispatch the fused paged sweep: the Pallas kernel when requested
-    and legal (whole-page cache, page-multiple ``block_s``, no sliding
-    window — the kernel masks only cache length), the blocked reference
-    with the same schedule otherwise.  ``k_scale``/``v_scale`` select
-    the int8 dequant-fused variants on both paths.
+    """Dispatch the fused paged sweep: the Pallas kernel when requested,
+    the blocked reference with the same schedule otherwise.  Sliding
+    windows always take the reference (the kernel masks only cache
+    length); a requested kernel on an illegal geometry (a cache or
+    ``block_s`` that is not whole pages) raises ``ValueError`` rather
+    than running the reference in its place.  ``k_scale``/``v_scale``
+    select the int8 dequant-fused variants on both paths.
 
     Example::
 
         o = paged_decode_attention(q, kc, vc, tables, clen,
                                    page_block=16, block_s=64)
     """
-    t = k_cache.shape[1]
-    if (use_pallas and window is None and t % page_block == 0
-            and block_s % page_block == 0 and block_s >= page_block):
-        b = q.shape[0]
-        clen = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,))
+    if use_pallas and window is None:
         return paged_decode_attention_pallas(
-            q, k_cache, v_cache, tables, clen, page_block=page_block,
+            q, k_cache, v_cache, tables, cache_len, page_block=page_block,
             block_s=block_s, scale=scale, k_scale=k_scale,
             v_scale=v_scale, interpret=interpret)
     return paged_decode_attention_ref(

@@ -171,7 +171,7 @@ def _dequant_gather_kernel(table_ref, c_ref, s_ref, o_ref):
     # materialize at f32 width outside this block
     del table_ref
     o_ref[...] = (c_ref[...].astype(o_ref.dtype)
-                  * s_ref[...][:, None, :, None].astype(o_ref.dtype))
+                  * s_ref[...][..., None].astype(o_ref.dtype))
 
 
 def paged_dequant_gather_pallas(cache: jax.Array, scale: jax.Array,
@@ -179,9 +179,11 @@ def paged_dequant_gather_pallas(cache: jax.Array, scale: jax.Array,
                                 out_dtype=jnp.float32,
                                 interpret: bool = False) -> jax.Array:
     """Pallas fused dequant-gather: grid step (b, i) DMAs physical int8
-    block ``tables[b, i]`` and its (1, G) scale row — both BlockSpecs
+    block ``tables[b, i]`` and its (1, 1, G) scale row — both BlockSpecs
     read the same scalar-prefetched flat block index — and writes the
-    dequantized logical block."""
+    dequantized logical block.  The scales are viewed as (B*nb, 1, G) so
+    the scale block's last two dims are whole, as the TPU tiling
+    requires."""
     b, t = cache.shape[:2]
     bs = block_size
     nb = t // bs
@@ -190,7 +192,7 @@ def paged_dequant_gather_pallas(cache: jax.Array, scale: jax.Array,
     pid = jnp.maximum(tables[:, :nb], 0).astype(jnp.int32)
     flat_block = (pid % b) * nb + (pid // b)              # (B, nb)
     blocks = cache.reshape((b * nb, bs) + cache.shape[2:])
-    scale_flat = scale.reshape(b * nb, g)
+    scale_flat = scale.reshape(b * nb, 1, g)
     tail = cache.shape[2:]
     ones = (0,) * len(tail)
 
@@ -202,8 +204,8 @@ def paged_dequant_gather_pallas(cache: jax.Array, scale: jax.Array,
             in_specs=[
                 pl.BlockSpec((1, bs) + tail,
                              lambda bi, i, tbl: (tbl[bi, i], 0) + ones),
-                pl.BlockSpec((1, g),
-                             lambda bi, i, tbl: (tbl[bi, i], 0)),
+                pl.BlockSpec((1, 1, g),
+                             lambda bi, i, tbl: (tbl[bi, i], 0, 0)),
             ],
             out_specs=pl.BlockSpec(
                 (1, bs) + tail,

@@ -11,6 +11,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import TpuParams, ceil_div, round_up
 from repro.core.mapper import MappingPolicy, resolve_lws
@@ -60,6 +61,8 @@ def rmsnorm_pallas(
         in_specs=[pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
                   pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=hw.vmem_budget_bytes),
         interpret=interpret,
     )(xp, g2)
     return out[:tokens] if tp != tokens else out

@@ -6,6 +6,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import TpuParams
 from repro.core.mapper import BlockPlan, MappingPolicy, plan_vector_blocks
@@ -44,6 +45,8 @@ def saxpy_pallas(
                   pl.BlockSpec((block,), lambda i: (i,)),
                   pl.BlockSpec((block,), lambda i: (i,))],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=hw.vmem_budget_bytes),
         interpret=interpret,
     )(a1, xp, yp)
     return out[:n] if pad else out

@@ -23,7 +23,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import TpuParams
-from repro.core.compat import tpu_compiler_params
 
 
 def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, o_ref, state_ref):
@@ -76,7 +75,7 @@ def ssd_pallas_single(x, a, b, c, *, chunk: int, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((chunk, p), lambda i: (i, 0)),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import TpuParams, round_up
 from repro.core.mapper import MappingPolicy, resolve_lws
@@ -99,6 +100,8 @@ def gaussian_blur_pallas(
         functools.partial(_row_pass_kernel, taps=taps),
         out_shape=jax.ShapeDtypeStruct((hp_, w), img.dtype),
         grid=grid, in_specs=[spec], out_specs=spec,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=hw.vmem_budget_bytes),
         interpret=interpret,
     )(x)
 
@@ -115,6 +118,8 @@ def gaussian_blur_pallas(
                          lambda i: (jnp.minimum(i + 1, nb - 1), 0)),
         ],
         out_specs=spec,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=hw.vmem_budget_bytes),
         interpret=interpret,
     )(rows, rows, rows)
     return out[:h] if hp_ != h else out

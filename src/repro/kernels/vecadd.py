@@ -11,6 +11,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import TpuParams
 from repro.core.mapper import BlockPlan, MappingPolicy, plan_vector_blocks
@@ -47,6 +48,8 @@ def vecadd_pallas(
         in_specs=[pl.BlockSpec((block,), lambda i: (i,)),
                   pl.BlockSpec((block,), lambda i: (i,))],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=hw.vmem_budget_bytes),
         interpret=interpret,
     )(xp, yp)
     return out[:n] if pad else out

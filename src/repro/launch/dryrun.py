@@ -152,8 +152,6 @@ def lower_cell(arch: str, shape_name: str, mesh, mesh_name: str,
         peak_memory=cost.peak_memory)
     # corroboration: raw compiled numbers (loop bodies counted once)
     raw_cost = compiled.cost_analysis()
-    if isinstance(raw_cost, list):
-        raw_cost = raw_cost[0]
     raw_coll = collective_stats_from_hlo(hlo_text, chips)
 
     mem = {}

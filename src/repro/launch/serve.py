@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.mapper import MappingPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import BucketSpec, ServeEngine, TrafficConfig, drive
 from repro.tuner import MEASURE_MODES
 
@@ -151,6 +152,7 @@ def main():
     ap.add_argument("--metrics-json", metavar="PATH", default=None,
                     help="dump the ServeReport summary as JSON")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     vocab = (cfg if args.full else cfg.reduced()).vocab_size

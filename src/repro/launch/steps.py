@@ -25,7 +25,6 @@ from repro.core.mapper import MappingPolicy, MeshPlan, plan_microbatch
 from repro.models.model import Model
 from repro.optim import AdamWConfig, adamw_update, compress_grads_int8, init_opt_state
 from repro.runtime.sharding import Plan, make_ctx
-from repro.core.compat import opt_barrier
 
 PyTree = Any
 
@@ -148,7 +147,7 @@ def make_train_step(model: Model, opt_cfg: AdamWConfig, plan: Plan,
                 batch)
 
             def acc_body(carry, mb):
-                mb = opt_barrier(mb)
+                mb = jax.lax.optimization_barrier(mb)
                 g_acc, loss_acc = carry
                 (loss, _), g = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, mb)

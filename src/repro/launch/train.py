@@ -28,6 +28,7 @@ from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.core.mapper import MappingPolicy
 from repro.data import data_config_for, make_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.launch.steps import (StepConfig, init_train_state, make_train_step,
                                 resolve_microbatches)
@@ -139,6 +140,7 @@ def main():
                     help="comma-separated steps for failure injection")
     ap.add_argument("--compress-grads", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     mesh = (make_production_mesh() if args.mesh == "prod"
             else make_local_mesh(1, 1))
     fail_at = tuple(int(x) for x in args.fail_at.split(",") if x)

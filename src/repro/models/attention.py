@@ -380,12 +380,15 @@ def pallas_prefill_attention(
     block_k: int,
     causal: bool = True,
     scale: Optional[float] = None,
+    q_offset=0,                   # int | traced scalar: query 0's position
     interpret: bool = False,
 ) -> jax.Array:
     """Run the Pallas flash kernel with the tuned (block_q, block_k) over
     the grouped prefill layout: one kernel instance per (batch, kv-group,
     q-head) row, the K/V rows shared across a group's R q-heads — the
-    executed form of the serving router's per-bucket prefill plan."""
+    executed form of the serving router's per-bucket prefill plan.
+    ``q_offset`` places a prompt chunk's queries inside a longer key
+    cache (chunked prefill's traced start)."""
     from repro.core.hw import detect
     from repro.core.mapper import MappingPolicy, attention_plan_for_blocks
     from repro.kernels.flash_attention import flash_attention_pallas
@@ -402,7 +405,7 @@ def pallas_prefill_attention(
     def one(q_row, k_row, v_row):
         return flash_attention_pallas(q_row, k_row, v_row, hw=hw,
                                       causal=causal, scale=scale, plan=plan,
-                                      interpret=interpret)
+                                      q_offset=q_offset, interpret=interpret)
 
     per_r = jax.vmap(one, in_axes=(0, None, None))        # R (K/V shared)
     per_g = jax.vmap(per_r, in_axes=(0, 0, 0))            # G

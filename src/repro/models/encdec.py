@@ -18,7 +18,6 @@ from repro.models.attention import (attention_block, attention_decode,
 from repro.models.layers import (ParamSpec, ShardCtx, embed, embed_specs,
                                  mlp, mlp_specs, rmsnorm, rope_tables,
                                  stack_specs, unembed)
-from repro.core.compat import opt_barrier
 
 
 def _enc_block_specs(cfg: ModelConfig) -> dict:
@@ -79,7 +78,7 @@ def encode(params: dict, frames: jax.Array, cfg: ModelConfig, *,
     x = ctx.p(x, "batch", "seq_sp", "embed")
 
     def body(x, lp):
-        lp = opt_barrier(lp)
+        lp = jax.lax.optimization_barrier(lp)
         h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
         a, _ = attention_block(lp["attn"], h, cfg, causal=False, ctx=ctx)
         x = ctx.p(x + a, "batch", "seq_sp", "embed")
@@ -110,7 +109,7 @@ def encdec_forward(params: dict, tokens: jax.Array, frames: jax.Array,
     cos, sin = rope_tables(jnp.arange(s), cfg.head_dim, cfg.rope_theta)
 
     def body(x, lp):
-        lp = opt_barrier(lp)
+        lp = jax.lax.optimization_barrier(lp)
         h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
         a, kv = attention_block(lp["attn"], h, cfg, cos=cos, sin=sin,
                                 causal=True, prefill_tiles=prefill_tiles,
@@ -177,9 +176,9 @@ def encdec_decode(params: dict, cache: dict, tokens: jax.Array,
 
     def body(x, xs):
         if quant:
-            lp, kc, vc, ks, vs, ck, cv = opt_barrier(xs)
+            lp, kc, vc, ks, vs, ck, cv = jax.lax.optimization_barrier(xs)
         else:
-            lp, kc, vc, ck, cv = opt_barrier(xs)
+            lp, kc, vc, ck, cv = jax.lax.optimization_barrier(xs)
             ks = vs = None
         h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
         a, kv = attention_decode(lp["attn"], h, cfg, kc, vc, pos,

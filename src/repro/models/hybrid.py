@@ -22,7 +22,6 @@ from repro.models.layers import (ParamSpec, ShardCtx, embed, embed_specs,
                                  stack_specs, unembed)
 from repro.models.ssm import (ssm_block, ssm_block_specs, ssm_cache_shape,
                               ssm_decode_step)
-from repro.core.compat import opt_barrier
 
 
 def n_groups(cfg: ModelConfig) -> int:
@@ -79,7 +78,7 @@ def hybrid_forward(params: dict, tokens: jax.Array, cfg: ModelConfig, *,
     gblocks = _group(params["blocks"], ng, k)
 
     def group_body(x, xs):
-        gp, gf = opt_barrier(xs)
+        gp, gf = jax.lax.optimization_barrier(xs)
         x, kv = _shared_attn(params["shared"], x, cfg, cos, sin, ctx,
                              prefill_tiles=prefill_tiles)
 
@@ -158,9 +157,9 @@ def hybrid_decode(params: dict, cache: dict, tokens: jax.Array,
 
     def group_body(x, xs):
         if quant:
-            gp, gf, kc, vc, ks, vs, st, cv = opt_barrier(xs)
+            gp, gf, kc, vc, ks, vs, st, cv = jax.lax.optimization_barrier(xs)
         else:
-            gp, gf, kc, vc, st, cv = opt_barrier(xs)
+            gp, gf, kc, vc, st, cv = jax.lax.optimization_barrier(xs)
             ks = vs = None
         h = rmsnorm(x, params["shared"]["ln1"], cfg.norm_eps)
         a, kv = attention_decode(params["shared"]["attn"], h, cfg,

@@ -170,7 +170,6 @@ def ssm_decode_step(params: dict, x: jax.Array, state: jax.Array,
 
 from repro.models.layers import (embed, embed_specs, stack_specs,  # noqa: E402
                                  unembed)
-from repro.core.compat import opt_barrier
 
 
 def ssm_block_specs(cfg: ModelConfig) -> dict:
@@ -195,7 +194,7 @@ def ssm_forward(params: dict, tokens: jax.Array, cfg: ModelConfig, *,
     x = ctx.p(x, "batch", "seq_sp", "embed")
 
     def body(x, layer_params):
-        layer_params = opt_barrier(layer_params)
+        layer_params = jax.lax.optimization_barrier(layer_params)
         h = rmsnorm(x, layer_params["ln"], cfg.norm_eps)
         if return_cache:
             y, cache = ssm_block(layer_params["ssm"], h, cfg, ctx,
@@ -244,7 +243,7 @@ def ssm_decode(params: dict, cache: dict, tokens: jax.Array,
     x = embed(params["embed"], tokens)
 
     def body(x, xs):
-        layer_params, st, cv = opt_barrier(xs)
+        layer_params, st, cv = jax.lax.optimization_barrier(xs)
         h = rmsnorm(x, layer_params["ln"], cfg.norm_eps)
         y, st, cv = ssm_decode_step(layer_params["ssm"], h, st, cv, cfg, ctx)
         return x + y, (st, cv)

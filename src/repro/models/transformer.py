@@ -21,14 +21,14 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import moe as moe_mod
 from repro.models.attention import (_cache_read, _cache_write,
-                                    _chunk_cache_write,
+                                    _chunk_cache_write, _pallas_mode,
                                     attention_block, attention_decode,
                                     attention_specs, _project_qkv,
+                                    pallas_prefill_attention,
                                     tiled_prefill_attention)
 from repro.models.layers import (NO_SHARD, ParamSpec, ShardCtx, embed,
                                  embed_specs, mlp, mlp_specs, rmsnorm,
                                  rope_tables, stack_specs, unembed)
-from repro.core.compat import opt_barrier
 
 LOCAL_ROPE_THETA = 10_000.0
 
@@ -135,7 +135,7 @@ def forward(
     def body(carry, xs):
         x, aux = carry
         # barrier: keep per-layer converts inside the loop (see optim.adamw)
-        layer_params, is_global = opt_barrier(xs)
+        layer_params, is_global = jax.lax.optimization_barrier(xs)
         x, kv, a = _block_fwd(layer_params, x, cfg, is_global=is_global,
                               cos_l=cos_l, sin_l=sin_l, cos_g=cos_g,
                               sin_g=sin_g, prefix_len=prefix_len,
@@ -213,7 +213,7 @@ def forward_banded(
     tailp = jax.tree.map(lambda a: a[n_full * gsz:], params["blocks"])
 
     def group_body(x, gp):
-        gp = opt_barrier(gp)
+        gp = jax.lax.optimization_barrier(gp)
         loc = jax.tree.map(lambda a: a[:ratio], gp)
         glob = jax.tree.map(lambda a: a[ratio], gp)
         x, kvs_l = jax.lax.scan(lambda xx, lp: local_block(lp, xx), x, loc)
@@ -226,7 +226,7 @@ def forward_banded(
     x, gcaches = jax.lax.scan(group_body, x, grouped)
 
     def tail_body(x, lp):
-        lp = opt_barrier(lp)
+        lp = jax.lax.optimization_barrier(lp)
         x, kv = local_block(lp, x)
         return x, (kv if return_cache else None)
 
@@ -289,8 +289,9 @@ def chunk_prefill_step(
 
     The chunk's queries attend over the growing cache (everything written
     by earlier chunks plus this chunk's own keys) through the same
-    tile-honouring sweep the whole-prompt prefill executes
-    (``tiled_prefill_attention``), with ``q_offset = cache["pos"]`` kept
+    tiles the whole-prompt prefill executes — the Pallas flash kernel on
+    the kernel path, ``tiled_prefill_attention`` otherwise — with
+    ``q_offset = cache["pos"]`` kept
     TRACED — one compilation serves every chunk of every prompt at a
     given (chunk, cache_len) shape.  The chunk's k/v land in the cache at
     positions ``pos .. pos+C-1`` via the same positional write the decode
@@ -317,9 +318,10 @@ def chunk_prefill_step(
     # default tiles: one query tile over the chunk, keys swept in 512s —
     # the untiled reference schedule (serving always passes tuned tiles)
     bq, bk = prefill_tiles if prefill_tiles is not None else (c, 512)
+    use_pallas, interpret = _pallas_mode()
 
     def body(x, xs):
-        layer_params, is_global, k_c, v_c = opt_barrier(xs)
+        layer_params, is_global, k_c, v_c = jax.lax.optimization_barrier(xs)
         cos = jnp.where(is_global, cos_g, cos_l) if cfg.local_global_ratio else cos_g
         sin = jnp.where(is_global, sin_g, sin_l) if cfg.local_global_ratio else sin_g
         h = rmsnorm(x, layer_params["ln1"], cfg.norm_eps)
@@ -329,10 +331,17 @@ def chunk_prefill_step(
         # rather than clamp-clobber the seeded prefix rows
         k_c = _chunk_cache_write(k_c, k, start)
         v_c = _chunk_cache_write(v_c, v, start)
-        o = tiled_prefill_attention(
-            q, _cache_read(k_c, x.dtype), _cache_read(v_c, x.dtype),
-            block_q=bq, block_k=bk, causal=True,
-            window=_layer_window(cfg, is_global), q_offset=start)
+        kr, vr = _cache_read(k_c, x.dtype), _cache_read(v_c, x.dtype)
+        win = _layer_window(cfg, is_global)
+        if use_pallas and win is None:
+            o = pallas_prefill_attention(q, kr, vr, block_q=bq, block_k=bk,
+                                         q_offset=start, interpret=interpret)
+        else:
+            # sliding windows stay on the reference sweep (the kernel
+            # masks causality only), which honours the same tiles
+            o = tiled_prefill_attention(q, kr, vr, block_q=bq, block_k=bk,
+                                        causal=True, window=win,
+                                        q_offset=start)
         a = jnp.einsum("bshk,hkd->bsd", o.reshape(b, c, -1, cfg.head_dim),
                        layer_params["attn"]["wo"])
         x = x + a
@@ -387,9 +396,11 @@ def decode_step(
 
     def body(x, xs):
         if quant:
-            layer_params, is_global, k_c, v_c, ks, vs = opt_barrier(xs)
+            layer_params, is_global, k_c, v_c, ks, vs = \
+                jax.lax.optimization_barrier(xs)
         else:
-            layer_params, is_global, k_c, v_c = opt_barrier(xs)
+            layer_params, is_global, k_c, v_c = \
+                jax.lax.optimization_barrier(xs)
             ks = vs = None
         cos = jnp.where(is_global, cos_g, cos_l) if cfg.local_global_ratio else cos_g
         sin = jnp.where(is_global, sin_g, sin_l) if cfg.local_global_ratio else sin_g

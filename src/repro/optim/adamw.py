@@ -14,7 +14,6 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from repro.core.compat import opt_barrier
 
 PyTree = Any
 
@@ -140,7 +139,7 @@ def adamw_update(
                     # the barrier pins the per-layer f32 converts inside
                     # the loop; without it XLA hoists convert(slice(x))
                     # into convert(x) — full stacked f32 copies
-                    xs = opt_barrier(xs)
+                    xs = jax.lax.optimization_barrier(xs)
                     return None, _update_subtree(*xs, **kw)
                 _, (new_p[key], new_m[key], new_v[key]) = jax.lax.scan(
                     body, None, sub)

@@ -460,26 +460,19 @@ def measure_value(
     def fn(*arrays):
         return spec.run(plan, hw, interpret, *arrays, **kwargs)
 
-    jitted = jax.jit(fn)
+    # a compile failure raises: a candidate the compiler refuses must
+    # not be timed through some other executable
+    compiled = jax.jit(fn).lower(*args).compile()
+    runner = lambda: compiled(*args)
     xla_flops = xla_bytes = None
-    try:
-        compiled = jitted.lower(*args).compile()
-    except Exception:
-        # AOT path unavailable (some backends/interpret corners): fall
-        # back to the jitted callable — warmup still absorbs the trace.
-        runner = lambda: jitted(*args)
-    else:
-        runner = lambda: compiled(*args)
-        if with_cost_analysis:
-            try:
-                cost = compiled.cost_analysis()
-                if isinstance(cost, list):   # older jax returns [dict]
-                    cost = cost[0] if cost else {}
-                if cost:
-                    xla_flops = float(cost.get("flops", 0.0)) or None
-                    xla_bytes = float(cost.get("bytes accessed", 0.0)) or None
-            except Exception:
-                pass          # stats are optional; the executable is not
+    if with_cost_analysis:
+        try:
+            cost = compiled.cost_analysis()
+            if cost:
+                xla_flops = float(cost.get("flops", 0.0)) or None
+                xla_bytes = float(cost.get("bytes accessed", 0.0)) or None
+        except Exception:
+            pass          # stats are optional; the executable is not
 
     stats = time_callable(runner, warmup=warmup, reps=reps)
     flops, byts = synth.features(desc)

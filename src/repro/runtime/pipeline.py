@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.compat import shard_map_compat
 from repro.core.hw import ceil_div
 
 PyTree = Any
@@ -113,11 +112,11 @@ def pipeline_apply(
             axis)
         return outs[None]
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         ranked, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params), P()),
         out_specs=P(axis),
-        check=False,
+        check_vma=False,
     )
     out = fn(stage_params, x)      # (S, n_micro, mb, s, d), S identical copies
     return out[0]

@@ -280,9 +280,9 @@ def choose_serve_mesh(cfg: ModelConfig, n_chips: int = 256,
 
 
 def make_serve_mesh(cfg: ModelConfig, n_chips: int = 256):
-    from repro.launch.mesh import make_mesh_compat
     dp, tp = choose_serve_mesh(cfg, n_chips)
-    return make_mesh_compat((dp, tp), ("data", "model"))
+    return jax.make_mesh((dp, tp), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def param_pspec(spec: ParamSpec, plan: Plan) -> P:

@@ -63,6 +63,38 @@ def _run(cfg, params, prompts=PROMPTS, **kw):
 
 
 class TestDenseExactness:
+    def test_pallas_flash_chunk_step_matches_reference_sweep(
+            self, dense_setup):
+        """Under the kernel path (interpret mode) the chunk step runs the
+        Pallas flash kernel with the chunk's traced start as its query
+        offset: chunk by chunk, logits and cache match the reference
+        tile sweep."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from repro.kernels import ops
+        from repro.models import build_model
+
+        cfg, params = dense_setup
+        model = build_model(cfg)
+        toks = jnp.asarray(np.arange(3, 27, dtype=np.int32)[None])
+        out = {}
+        for mode in ("ref", "interpret"):
+            with ops.force(mode):
+                step = jax.jit(lambda p, c, t, n: model.prefill_chunk(
+                    p, c, t, n, prefill_tiles=(8, 128)))
+                cache, logits = model.init_cache(1, 32), []
+                for s in range(0, 24, 8):
+                    lg, cache = step(params, cache, toks[:, s:s + 8],
+                                     jnp.int32(8))
+                    logits.append(np.asarray(lg))
+            out[mode] = np.concatenate(logits, 1), np.asarray(cache["k"])
+        np.testing.assert_allclose(out["interpret"][0], out["ref"][0],
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(out["interpret"][1], out["ref"][1],
+                                   rtol=1e-6, atol=1e-6)
+
     def test_chunked_matches_whole_prefill(self, dense_setup):
         cfg, params = dense_setup
         whole, _ = _run(cfg, params, prefill_chunk=None)

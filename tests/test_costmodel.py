@@ -26,12 +26,7 @@ TINY = ModelConfig(name="tiny", family="dense", num_layers=1, d_model=256,
 
 
 def _flops(compiled) -> float:
-    """cost_analysis() returns [dict] on older jax (roofline.py normalizes
-    the same way)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0]
-    return float(cost["flops"])
+    return float(compiled.cost_analysis()["flops"])
 
 
 def test_xla_artifact_scan_flops_counted_once():

@@ -5,7 +5,7 @@ pytest.importorskip("hypothesis")
 import hypothesis.strategies as st
 from hypothesis import given, settings
 
-from repro.core.hw import TPU_REGISTRY, VortexParams, ceil_div
+from repro.core.hw import TPU_REGISTRY, VortexParams, ceil_div, detect
 from repro.core import workload as W
 from repro.core.mapper import (MappingPolicy, Regime, classify_regime,
                                plan_attention_blocks, plan_matmul_blocks,
@@ -111,3 +111,26 @@ class TestMoECapacity:
         cap = plan_moe_capacity(t, e, k, ep_size=1)
         assert cap * e >= t * k          # slots cover all routed tokens
         assert cap % 8 == 0
+
+
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_detect_keys_tpus_by_device_kind(monkeypatch):
+    """A v5e is recognised by its ``device_kind``; a TPU kind with no
+    registry entry is an error, not a v5e guess; a CPU gets the
+    ``cpu_sim`` stand-in."""
+    import jax
+
+    monkeypatch.setattr(jax, "devices",
+                        lambda: [_Dev("tpu", "TPU v5 lite")] * 4)
+    assert detect() == TPU_REGISTRY["tpu_v5e"].with_chips(4)
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("tpu", "TPU v4")])
+    assert detect() == TPU_REGISTRY["tpu_v4"]
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("tpu", "TPU v6 lite")])
+    with pytest.raises(ValueError, match="unknown TPU"):
+        detect()
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("cpu", "cpu")])
+    assert detect() == TPU_REGISTRY["cpu_sim"]

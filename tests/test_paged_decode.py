@@ -129,6 +129,34 @@ def test_fused_ref_honours_sliding_window():
         np.testing.assert_allclose(got, expected, rtol=1e-5, atol=1e-5)
 
 
+def test_pallas_request_on_illegal_geometry_raises():
+    """A requested kernel on a cache or ``block_s`` that is not whole
+    pages raises instead of running the reference in its place, while a
+    sliding window still takes the documented reference path."""
+    import jax.numpy as jnp
+
+    from repro.kernels.paged_decode_attention import (
+        paged_decode_attention, paged_decode_attention_ref)
+
+    bs = 16
+    q, k, v, tables, clen = _paged_case(2, bs=bs)
+    qj, kj, vj = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
+    tj, cj = jnp.asarray(tables), jnp.asarray(clen)
+    for kc, vc, block_s in ((kj[:, :40], vj[:, :40], 32),   # ragged cache
+                            (kj, vj, 24),                    # ragged block
+                            (kj, vj, 8)):                    # sub-page block
+        with pytest.raises(ValueError, match="whole pages"):
+            paged_decode_attention(qj, kc, vc, tj, cj, page_block=bs,
+                                   block_s=block_s, use_pallas=True,
+                                   interpret=True)
+    got = paged_decode_attention(qj, kj, vj, tj, cj, page_block=bs,
+                                 block_s=32, window=5, use_pallas=True,
+                                 interpret=True)
+    ref = paged_decode_attention_ref(qj, kj, vj, tj, cj, page_block=bs,
+                                     block_s=32, window=5)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
 # --------------------------------------------------------------------------- #
 # Engine-level parity: all five families, recycling + growth
 # --------------------------------------------------------------------------- #

@@ -45,8 +45,8 @@ PIPELINE_BODY = """
     stages = split_stages(params, S)
     x = jax.random.normal(jax.random.key(1), (n_micro, mb, 4, d))
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((S,), ("pod",))
+    mesh = jax.make_mesh((S,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     ref = sequential_apply(stage_fn, stages, x)
 """
 
