@@ -1,0 +1,12 @@
+"""Tests of the benchmark harness, on the CPU at small sizes:
+
+    python -m pytest bench/tests
+"""
+
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (BENCH, BENCH.parent / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
