@@ -88,26 +88,32 @@ def _one_pass(eng, traffic):
     return s.tokens_per_s, report.outputs
 
 
+def _tick(t) -> None:
+    """The spans ``ServeEngine._decode_tick`` opens per step: the tick
+    (7 attributes) with its device wait nested, then the sample."""
+    with t.span("decode_tick", bucket=128, decode_block=128,
+                paged_decode_block=16, live=4, slots=4, ctx_tokens=300,
+                pool_len=128):
+        with t.span("wait"):
+            pass
+    with t.span("sample") as sp:
+        sp.set(rows=4)
+
+
 def _tick_cost_s() -> float:
     """Directly time one decode tick's worth of instrumentation on a
-    fresh Tracer: one 5-attribute span + two counter bumps + a gauge —
-    exactly the calls ``ServeEngine._decode_tick`` makes per step."""
+    fresh Tracer — exactly the spans ``ServeEngine._decode_tick`` opens
+    per step."""
     from repro.obs import Tracer
 
-    t = Tracer(capacity=_COST_ITERS + 16)
-    # warm the span/counter paths before timing
+    t = Tracer(capacity=3 * _COST_ITERS + 16)
+    # warm the span paths before timing
     for _ in range(100):
-        with t.span("decode_tick", bucket=128, decode_block=128,
-                    paged_decode_block=16, live=4, slots=4):
-            pass
+        _tick(t)
     t.clear()
     t0 = time.perf_counter()
     for _ in range(_COST_ITERS):
-        with t.span("decode_tick", bucket=128, decode_block=128,
-                    paged_decode_block=16, live=4, slots=4):
-            t.count("decode_ticks")
-            t.count("tokens_decoded", 4)
-            t.gauge("live_slots", 4)
+        _tick(t)
     return (time.perf_counter() - t0) / _COST_ITERS
 
 

@@ -118,5 +118,6 @@ def decode_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=hw.vmem_budget_bytes),
         interpret=interpret,
+        name="decode_attention",
     )(clen, q.reshape(1, d), kp, vp)
     return out[0]

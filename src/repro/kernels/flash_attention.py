@@ -130,5 +130,6 @@ def flash_attention_pallas(
             vmem_limit_bytes=hw.vmem_budget_bytes,
         ),
         interpret=interpret,
+        name="flash_attention",
     )(off, qp, kp, vp)
     return out[:sq] if sqp != sq else out

@@ -334,6 +334,7 @@ def paged_decode_attention_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((b, g, r, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(flat_block, clen, *operands)
     return out
 

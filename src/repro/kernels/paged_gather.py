@@ -120,6 +120,7 @@ def paged_gather_pallas(cache: jax.Array, tables: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct(blocks.shape, cache.dtype),
         interpret=interpret,
+        name="paged_gather",
     )(flat_block, blocks)
     return out.reshape(cache.shape)
 
@@ -213,6 +214,7 @@ def paged_dequant_gather_pallas(cache: jax.Array, scale: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct(blocks.shape, out_dtype),
         interpret=interpret,
+        name="paged_dequant_gather",
     )(flat_block, blocks, scale_flat)
     return out.reshape(cache.shape[:2] + tail)
 

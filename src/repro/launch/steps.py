@@ -191,6 +191,10 @@ def abstract_train_state(model: Model, plan: Optional[Plan] = None) -> dict:
 # --------------------------------------------------------------------------- #
 # Serve steps
 # --------------------------------------------------------------------------- #
+#
+# Each serve step runs under a ``jax.named_scope`` (``serve.decode_step``,
+# ``serve.chunk_prefill_step``, ``serve.prefill_step``), so its operations
+# carry that name in their HLO ``op_name`` metadata.
 
 
 def make_prefill_step(model: Model, plan: Plan, max_len: Optional[int],
@@ -219,8 +223,9 @@ def make_prefill_step(model: Model, plan: Plan, max_len: Optional[int],
                      pad_to=None):
         ml = max_len if max_len is not None else (
             pad_to if pad_to is not None else batch["tokens"].shape[1])
-        return model.prefill(params, batch, ml, last_pos=last_pos,
-                             prefill_tiles=prefill_tiles, ctx=ctx)
+        with jax.named_scope("serve.prefill_step"):
+            return model.prefill(params, batch, ml, last_pos=last_pos,
+                                 prefill_tiles=prefill_tiles, ctx=ctx)
 
     return prefill_step
 
@@ -238,8 +243,9 @@ def make_chunk_prefill_step(model: Model, plan: Plan,
 
     def chunk_prefill_step(params, cache, tokens, n_valid,
                            prefill_tiles=None):
-        return model.prefill_chunk(params, cache, tokens, n_valid,
-                                   prefill_tiles=prefill_tiles, ctx=ctx)
+        with jax.named_scope("serve.chunk_prefill_step"):
+            return model.prefill_chunk(params, cache, tokens, n_valid,
+                                       prefill_tiles=prefill_tiles, ctx=ctx)
 
     return chunk_prefill_step
 
@@ -261,10 +267,11 @@ def make_decode_step(model: Model, plan: Plan,
     def decode_step(params, cache, tokens, decode_block=None,
                     page_tables=None, page_block=None,
                     paged_decode_block=None):
-        return model.decode_step(params, cache, tokens, ctx=ctx,
-                                 decode_block=decode_block,
-                                 page_tables=page_tables,
-                                 page_block=page_block,
-                                 paged_decode_block=paged_decode_block)
+        with jax.named_scope("serve.decode_step"):
+            return model.decode_step(params, cache, tokens, ctx=ctx,
+                                     decode_block=decode_block,
+                                     page_tables=page_tables,
+                                     page_block=page_block,
+                                     paged_decode_block=paged_decode_block)
 
     return decode_step

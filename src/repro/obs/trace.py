@@ -2,9 +2,9 @@
 
 The paper's method is *trace observation driving runtime mapping* — the
 engine cannot retune what it cannot see.  This module is the seeing
-half: a dependency-free ``Tracer`` every runtime layer threads its
-events through, designed around the same disciplines the rest of the
-stack already follows:
+half: a ``Tracer`` every runtime layer threads its events through,
+designed around the same disciplines the rest of the stack already
+follows:
 
   * **nestable spans** — ``with tracer.span("decode_tick", bucket=256)``
     records a timed interval carrying arbitrary attributes (the bucket
@@ -20,12 +20,18 @@ stack already follows:
     without growing memory, oldest spans evicted first;
   * **thread-safe counters/gauges** — monotonic counters
     (``count("tokens", 4)``) and last-value gauges
-    (``gauge("live_slots", 3)``) behind one lock;
+    (``gauge("queue_depth", 3)``) behind one lock;
+  * **on the profiler's clock too** — while the JAX profiler records,
+    every span of an enabled ``Tracer`` also enters a
+    ``jax.profiler.TraceAnnotation`` named ``serve.<name>`` carrying its
+    attributes, so the engine's phases sit beside the device's
+    operations in one profiler trace; the ``SpanRecord`` times stay on
+    the tracer's own clock;
   * **zero cost when off** — the module-level default tracer is a
     ``NullTracer`` whose ``span``/``instant``/``count`` are constant
-    no-ops, and tracing never enters jitted code at all, so the lowered
-    HLO with tracing disabled is byte-identical to the untraced build
-    (``tests/test_obs.py`` pins this).
+    no-ops that annotate nothing, and tracing never enters jitted code
+    at all, so the lowered HLO with tracing disabled is byte-identical
+    to the untraced build (``tests/test_obs.py`` pins this).
 
 Export (Perfetto JSON / JSONL), per-bucket aggregation into the
 profiler's ``TraceStore``, and drift detection live in the sibling
@@ -40,6 +46,8 @@ import dataclasses
 import threading
 import time
 from typing import Any, Callable, Iterator, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "OBS_SCHEMA_VERSION",
@@ -60,6 +68,17 @@ OBS_SCHEMA_VERSION = 1
 
 #: default ring-buffer capacity (finished spans kept before eviction).
 DEFAULT_CAPACITY = 65536
+
+#: prefix of a span's name in the profiler trace (``serve.decode_tick``)
+PROFILER_PREFIX = "serve."
+
+
+def _annotation_meta(attrs: dict) -> dict:
+    """A span's attributes as profiler annotation metadata: numbers,
+    strings and booleans as they are, anything else (tuples, None) as
+    text."""
+    return {k: v if isinstance(v, (bool, int, float, str)) else str(v)
+            for k, v in attrs.items()}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,6 +130,8 @@ class Span:
 
     Attributes set at open time or via ``set`` land in the finished
     ``SpanRecord``; the record is appended to the tracer's ring on exit.
+    While the profiler records, the span's extent is also a
+    ``serve.<name>`` annotation carrying the same attributes.
 
     Example::
 
@@ -119,7 +140,8 @@ class Span:
             sp.set(live=3)
     """
 
-    __slots__ = ("_tracer", "name", "attrs", "t0", "sid", "parent", "tid")
+    __slots__ = ("_tracer", "name", "attrs", "t0", "sid", "parent", "tid",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -129,17 +151,28 @@ class Span:
         self.sid = 0
         self.parent: Optional[int] = None
         self.tid = 0
+        self._ann: Optional[TraceAnnotation] = None
 
     def set(self, **attrs: Any) -> "Span":
         """Attach/overwrite attributes on the open span (returns self)."""
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**_annotation_meta(attrs))
         return self
 
     def __enter__(self) -> "Span":
         self._tracer._open(self)
+        # the annotation costs one check while the profiler is off
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(PROFILER_PREFIX + self.name,
+                                        **_annotation_meta(self.attrs))
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         self._tracer._close(self)
         return False
 
@@ -264,7 +297,7 @@ class Tracer:
 
         Example::
 
-            tracer.count("tokens_decoded", 4)
+            tracer.count("pool_growths")
         """
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + n
@@ -274,7 +307,7 @@ class Tracer:
 
         Example::
 
-            tracer.gauge("live_slots", 3)
+            tracer.gauge("queue_depth", 3)
         """
         with self._lock:
             self._gauges[name] = value
@@ -307,7 +340,8 @@ class Tracer:
 
 
 class NullTracer:
-    """The disabled tracer: every operation is a constant no-op.
+    """The disabled tracer: every operation is a constant no-op, and
+    nothing reaches the profiler trace.
 
     Instrumented call sites write unconditionally against this
     interface — ``tracer.span(...)`` returns one shared null context

@@ -140,13 +140,17 @@ class ServeEngine:
     ``benchmarks/serve_bench.py`` measures).
 
     ``tracer`` threads an ``obs.Tracer`` through the whole runtime:
-    every prefill admit and decode tick becomes a span carrying its
-    bucket key and executed plan, router/tuner resolutions record their
-    provenance, and pool growth / slot recycling emit instants — see
-    docs/OBSERVABILITY.md.  ``None`` binds the ambient tracer at
-    construction time (``obs.trace.get_tracer()``, the null tracer by
-    default), so an untraced engine pays constant no-ops and its jitted
-    steps lower to byte-identical HLO (``tests/test_obs.py`` pins this).
+    every loop iteration is a ``step`` span with its phases nested
+    (admit, prefill chunk, decode tick, device wait, sample, retire,
+    report), every prefill admit and decode tick carries its bucket key
+    and executed plan, router/tuner resolutions record their
+    provenance, and pool growth / slot recycling emit instants; while
+    the JAX profiler records, the spans also appear in its trace as
+    ``serve.<name>`` — see docs/OBSERVABILITY.md.  ``None`` binds the
+    ambient tracer at construction time (``obs.trace.get_tracer()``, the
+    null tracer by default), so an untraced engine pays constant no-ops
+    and its jitted steps lower to byte-identical HLO
+    (``tests/test_obs.py`` pins this).
 
     Example::
 
@@ -510,26 +514,28 @@ class ServeEngine:
             logits, rcache = self._prefill(self.params, batch, last,
                                            prefill_tiles=tiles,
                                            pad_to=(off + pb) if off else None)
-            logits = jax.block_until_ready(logits)
+            with self.obs.span("wait"):
+                logits = jax.block_until_ready(logits)
             self.metrics.add_prefill_time(time.perf_counter() - t0)
-        self.obs.count("admits")
 
-        pm = sm = None
-        if self.paged:
-            blocks = self.pool.lease(req.rid).blocks
-            self._tables[req.slot] = self.pool.block_table(req.rid)
-            self._tables_dev = None
-            pm = self._page_map(blocks, off + plen)
-            if self.kv_spec.quantized:
-                sm = self._scale_map(blocks)
-        self._cache = self.adapter.write_row(self._cache, req.slot, rcache,
-                                             off + plen,
-                                             self.pool.kv_len, page_map=pm,
-                                             scale_map=sm,
-                                             page_block=self._block_size)
-        first = int(jnp.argmax(logits[0, -1]))
-        req.generated.append(first)
-        self._tokens[req.slot, 0] = first
+        with self.obs.span("write_row", rid=req.rid, prompt_len=plen):
+            pm = sm = None
+            if self.paged:
+                blocks = self.pool.lease(req.rid).blocks
+                self._tables[req.slot] = self.pool.block_table(req.rid)
+                self._tables_dev = None
+                pm = self._page_map(blocks, off + plen)
+                if self.kv_spec.quantized:
+                    sm = self._scale_map(blocks)
+            self._cache = self.adapter.write_row(self._cache, req.slot,
+                                                 rcache, off + plen,
+                                                 self.pool.kv_len,
+                                                 page_map=pm, scale_map=sm,
+                                                 page_block=self._block_size)
+        with self.obs.span("sample", rows=1):
+            first = int(jnp.argmax(logits[0, -1]))
+            req.generated.append(first)
+            self._tokens[req.slot, 0] = first
         t = self._now()
         self.metrics.on_admit(req.rid, now)
         self.metrics.on_first_token(req.rid, t)
@@ -596,7 +602,6 @@ class ServeEngine:
         self._chunk_tasks.append(task)
         self._prefilling[req.rid] = task
         self.metrics.on_admit(req.rid, now)
-        self.obs.count("admits")
 
     def _radix_seed(self, task: _ChunkTask, m) -> None:
         """Seed a chunk task's private row cache from its radix match:
@@ -674,7 +679,8 @@ class ServeEngine:
             logits, task.cache = self._chunk_step(
                 self.params, task.cache, jnp.asarray(buf), jnp.int32(n),
                 prefill_tiles=task.tiles)
-            logits = jax.block_until_ready(logits)
+            with self.obs.span("wait"):
+                logits = jax.block_until_ready(logits)
             self.metrics.add_prefill_time(time.perf_counter() - t0)
         task.done += n
         if task.done >= len(task.toks):
@@ -683,36 +689,40 @@ class ServeEngine:
 
     def _finish_chunked(self, task: _ChunkTask, logits, n: int) -> None:
         req = task.req
-        pm = sm = None
-        if self.paged:
-            # publish the slot's table row only now — see _admit_chunked
-            self._tables[req.slot] = self.pool.block_table(req.rid)
-            self._tables_dev = None
-            pm = self._page_map(task.blocks, req.prompt_len,
-                                start=task.start)
-            if self.kv_spec.quantized:
-                sm = self._scale_map(task.blocks)
-            # decode appends land in the prompt's boundary block onward;
-            # sharing discipline requires that block be PRIVATE (shared
-            # blocks are read-only by contract)
-            assert self.pool.refcount(
-                task.blocks[req.prompt_len // self._block_size]) == 1, \
-                "decode-append block is shared"
-        self._cache = self.adapter.write_row(self._cache, req.slot,
-                                             task.cache, req.prompt_len,
-                                             self.pool.kv_len, page_map=pm,
-                                             scale_map=sm,
-                                             page_block=self._block_size,
-                                             start=task.start)
+        with self.obs.span("write_row", rid=req.rid,
+                           prompt_len=req.prompt_len):
+            pm = sm = None
+            if self.paged:
+                # publish the slot's table row only now — see
+                # _admit_chunked
+                self._tables[req.slot] = self.pool.block_table(req.rid)
+                self._tables_dev = None
+                pm = self._page_map(task.blocks, req.prompt_len,
+                                    start=task.start)
+                if self.kv_spec.quantized:
+                    sm = self._scale_map(task.blocks)
+                # decode appends land in the prompt's boundary block
+                # onward; sharing discipline requires that block be
+                # PRIVATE (shared blocks are read-only by contract)
+                assert self.pool.refcount(
+                    task.blocks[req.prompt_len // self._block_size]) == 1, \
+                    "decode-append block is shared"
+            self._cache = self.adapter.write_row(self._cache, req.slot,
+                                                 task.cache, req.prompt_len,
+                                                 self.pool.kv_len,
+                                                 page_map=pm, scale_map=sm,
+                                                 page_block=self._block_size,
+                                                 start=task.start)
         if self._radix is not None:
             # index the request's fully-written prompt blocks (shared
             # prefix nodes are reused; only new nodes retain); the
             # partial tail joins at retirement, once decode stops
             # appending into it
             self._radix.insert(req.prompt, task.blocks)
-        first = int(jnp.argmax(logits[0, n - 1]))
-        req.generated.append(first)
-        self._tokens[req.slot, 0] = first
+        with self.obs.span("sample", rows=1):
+            first = int(jnp.argmax(logits[0, n - 1]))
+            req.generated.append(first)
+            self._tokens[req.slot, 0] = first
         self.metrics.on_first_token(req.rid, self._now())
         self.obs.instant("prefill_complete", rid=req.rid,
                          prompt_len=req.prompt_len, chunk=task.chunk,
@@ -740,19 +750,27 @@ class ServeEngine:
                       # read back to gather-then-sweep (the ablation)
                       paged_decode_block=(plan.paged_decode_block
                                           if self.fused_decode else None))
+        # the summed context of the rows this tick decodes (each reads
+        # its prompt and every token it has generated)
+        ctx = (sum(r.prompt_len + len(r.generated)
+                   for r in self.scheduler.live
+                   if not r.done and r.rid not in self._prefilling)
+               if self.obs.enabled else None)
         # the span records the EXECUTED mapping: the fused block_s when
         # the paged read runs fused, the dense decode_block otherwise
         with self.obs.span("decode_tick", bucket=self.pool.kv_len,
                            decode_block=plan.decode_block,
                            paged_decode_block=kw.get("paged_decode_block"),
-                           live=len(self.scheduler.live), slots=self.slots):
+                           live=len(self.scheduler.live), slots=self.slots,
+                           ctx_tokens=ctx, pool_len=self.pool.kv_len):
             t0 = time.perf_counter()
             logits, self._cache = self._decode(self.params,
                                                dict(self._cache),
                                                jnp.asarray(self._tokens),
                                                decode_block=plan.decode_block,
                                                **kw)
-            logits = jax.block_until_ready(logits)
+            with self.obs.span("wait"):
+                logits = jax.block_until_ready(logits)
             dt = time.perf_counter() - t0
             self.metrics.add_decode_time(dt)
         if self.retune is not None:
@@ -765,33 +783,35 @@ class ServeEngine:
                              if plan.decode_block is not None
                              else (None, None))
             self.retune.observe_tick(self.pool.kv_len, kernel, value, dt)
-        lg = logits[:, 0] if logits.ndim == 3 else logits
-        nxt = np.asarray(jnp.argmax(lg, axis=-1), np.int32)
-        live = self.scheduler.live_by_slot()
-        n_dec = 0
-        for slot, req in live.items():
-            # rows still chunk-prefilling ride the step (their leased
-            # row is overwritten by write_row at completion) but their
-            # outputs are not real tokens yet
-            if not req.done and req.rid not in self._prefilling:
-                req.generated.append(int(nxt[slot]))
-                self._tokens[slot, 0] = int(nxt[slot])
-                n_dec += 1
-        self.metrics.on_step(self._now(), n_dec, self.slots)
-        self.obs.count("decode_ticks")
-        self.obs.count("tokens_decoded", n_dec)
-        self.obs.gauge("live_slots", n_dec)
+        with self.obs.span("sample") as sp:
+            lg = logits[:, 0] if logits.ndim == 3 else logits
+            nxt = np.asarray(jnp.argmax(lg, axis=-1), np.int32)
+            live = self.scheduler.live_by_slot()
+            n_dec = 0
+            for slot, req in live.items():
+                # rows still chunk-prefilling ride the step (their leased
+                # row is overwritten by write_row at completion) but
+                # their outputs are not real tokens yet
+                if not req.done and req.rid not in self._prefilling:
+                    req.generated.append(int(nxt[slot]))
+                    self._tokens[slot, 0] = int(nxt[slot])
+                    n_dec += 1
+            self.metrics.on_step(self._now(), n_dec, self.slots)
+            sp.set(rows=n_dec)
 
     # -- main loop --------------------------------------------------------
 
     def _retire_finished(self, on_complete) -> None:
-        now = self._now()
-        for req in self.scheduler.live:
-            eos = self.eos_id is not None and req.generated \
-                and req.generated[-1] == self.eos_id
-            if req.done or eos:
+        with self.obs.span("retire"):
+            now = self._now()
+            for req in self.scheduler.live:
+                eos = self.eos_id is not None and req.generated \
+                    and req.generated[-1] == self.eos_id
+                if not (req.done or eos):
+                    continue
                 slot = req.slot
-                if self._radix is not None and req.rid not in self._prefilling:
+                if self._radix is not None and \
+                        req.rid not in self._prefilling:
                     # the partial prompt-tail block becomes indexable
                     # only now — its owner stops appending decode tokens
                     self._radix.insert_tail(
@@ -808,53 +828,69 @@ class ServeEngine:
                     on_complete(req, now)
 
     def _admit_ready(self) -> None:
-        now = self._now()
-        self.scheduler.poll(now)
-        need = self.scheduler.peek_need_len()
-        if need is not None:
-            target = self.spec.quantize(need)
-            if target > self.pool.kv_len:
-                self._grow_pool(target)
-        for req in self.scheduler.admissible():
-            # resolve the bucket's tuned kernel plans BEFORE the request
-            # joins the pool — the runtime mapping decision of the paper,
-            # warm buckets answered by the tuning cache with zero probes
-            self._current_plan()
-            self._admit(req, now)
+        with self.obs.span("admit"):
+            now = self._now()
+            self.scheduler.poll(now)
+            need = self.scheduler.peek_need_len()
+            if need is not None:
+                target = self.spec.quantize(need)
+                if target > self.pool.kv_len:
+                    self._grow_pool(target)
+            for req in self.scheduler.admissible():
+                # resolve the bucket's tuned kernel plans BEFORE the
+                # request joins the pool — the runtime mapping decision
+                # of the paper, warm buckets answered by the tuning cache
+                # with zero probes
+                self._current_plan()
+                self._admit(req, now)
+
+    def _iterate(self, on_complete) -> bool:
+        """One iteration of the engine loop; False when the loop must
+        stop with requests still queued (nothing left to run)."""
+        self._admit_ready()
+        # one prefill chunk per loop iteration, interleaved with the
+        # decode tick below — long prompts advance without ever
+        # stalling the decoding pool for their whole length
+        stepped = self._prefill_tick()
+        decodable = any(r.rid not in self._prefilling
+                        for r in self.scheduler.live)
+        if decodable:
+            self._decode_tick()
+            self._retire_finished(on_complete)
+        elif not stepped:
+            nxt = self.scheduler.next_arrival
+            if nxt is not None:
+                self._fast_forward(nxt)    # idle: jump to next arrival
+            elif self.scheduler.backlog:
+                # queue head can never be seated (block budget): shed
+                # it rather than livelock — admission control's floor
+                self.scheduler.shed_head()
+            else:
+                return False
+        if self.retune is not None and self.retune.poll():
+            # the router's table changed under us (trial start or
+            # revert): drop the plan memo so the next tick re-reads it
+            self._plan_len = -1
+        return True
+
+    def _report(self) -> ServeReport:
+        with self.obs.span("report"):
+            return self.report()
 
     def run(self, *, on_complete=None,
             max_steps: Optional[int] = None) -> ServeReport:
-        """Drain the queue; returns the run's ``ServeReport``."""
+        """Drain the queue; returns the run's ``ServeReport``.  Each
+        loop iteration is one ``step`` span; the last one ends with the
+        report."""
         steps = 0
         while not self.scheduler.idle:
-            self._admit_ready()
-            # one prefill chunk per loop iteration, interleaved with the
-            # decode tick below — long prompts advance without ever
-            # stalling the decoding pool for their whole length
-            stepped = self._prefill_tick()
-            decodable = any(r.rid not in self._prefilling
-                            for r in self.scheduler.live)
-            if decodable:
-                self._decode_tick()
-                self._retire_finished(on_complete)
-            elif not stepped:
-                nxt = self.scheduler.next_arrival
-                if nxt is not None:
-                    self._fast_forward(nxt)    # idle: jump to next arrival
-                elif self.scheduler.backlog:
-                    # queue head can never be seated (block budget): shed
-                    # it rather than livelock — admission control's floor
-                    self.scheduler.shed_head()
-                else:
-                    break
-            if self.retune is not None and self.retune.poll():
-                # the router's table changed under us (trial start or
-                # revert): drop the plan memo so the next tick re-reads it
-                self._plan_len = -1
-            steps += 1
-            if max_steps is not None and steps >= max_steps:
-                break
-        return self.report()
+            with self.obs.span("step"):
+                more = self._iterate(on_complete)
+                steps += 1
+                if (not more or self.scheduler.idle
+                        or (max_steps is not None and steps >= max_steps)):
+                    return self._report()
+        return self._report()
 
     def report(self) -> ServeReport:
         """Snapshot the run's ``ServeReport`` (also returned by

@@ -169,8 +169,8 @@ def _sample_tracer():
                  paged_decode_block=32, tiles=(32, 128)):
         pass
     tr.instant("pool_grow", kv_len=128)
-    tr.count("decode_ticks", 3)
-    tr.gauge("live_slots", 2)
+    tr.count("pool_growths", 3)
+    tr.gauge("queue_depth", 2)
     return tr
 
 
@@ -180,8 +180,8 @@ class TestExport:
         path = write_trace(tr, str(tmp_path / "t.jsonl"))
         back = load_trace(path)
         assert back.meta == {"arch": "toy", "layers": 2}
-        assert back.counters() == {"decode_ticks": 3}
-        assert back.gauges() == {"live_slots": 2}
+        assert back.counters() == {"pool_growths": 3}
+        assert back.gauges() == {"queue_depth": 2}
         a, b = tr.spans(), back.spans()
         assert [r.name for r in b] == [r.name for r in a]
         assert [r.sid for r in b] == [r.sid for r in a]
@@ -233,7 +233,7 @@ class TestExport:
         (inst,) = by_ph["i"]
         assert inst["name"] == "pool_grow"
         assert {ev["name"] for ev in by_ph["C"]} == \
-            {"decode_ticks", "live_slots"}
+            {"pool_growths", "queue_depth"}
         assert doc["otherData"] == {"arch": "toy", "layers": 2}
 
     def test_chrome_json_round_trip(self, tmp_path):
@@ -313,10 +313,19 @@ class TestServingSpans:
 
     def test_counters_and_meta(self, traced_run):
         tracer, eng = traced_run
-        c = tracer.counters()
-        assert c["admits"] == 4
-        assert c["decode_ticks"] >= 1
-        assert c["tokens_decoded"] >= c["decode_ticks"]
+        spans = tracer.spans()
+        steps = {s.sid for s in spans if s.name == "step"}
+        # four admissions, at least one decode tick, and each tick's
+        # sample decoded at least one row
+        assert len([s for s in spans if s.name == "prefill"]) == 4
+        ticks = [s for s in spans if s.name == "decode_tick"]
+        decoded = [s.attrs["rows"] for s in spans
+                   if s.name == "sample" and s.parent in steps]
+        assert len(ticks) >= 1 and len(decoded) == len(ticks)
+        assert sum(decoded) >= len(ticks)
+        assert sum(decoded) == sum(st.live for st in eng.metrics.steps)
+        assert tracer.counters().get("pool_growths", 0) == eng.pool_growths
+        assert tracer.gauges() == {}
         m = tracer.meta
         assert m["layers"] == eng.cfg.num_layers
         assert m["head_dim"] == eng.cfg.head_dim
@@ -333,6 +342,133 @@ class TestServingSpans:
             assert r.n == len(r.samples)
             assert r.total_s == pytest.approx(sum(r.samples))
             assert r.median_s <= r.total_s
+
+
+class TestEngineLoopSpans:
+    """Every iteration of ``run()``'s loop is a ``step`` span, with the
+    iteration's phases nested in it."""
+
+    @staticmethod
+    def _ancestors(spans):
+        by_sid = {s.sid: s for s in spans}
+
+        def chain(s):
+            out = []
+            while s.parent is not None:
+                s = by_sid[s.parent]
+                out.append(s.name)
+            return out
+        return chain
+
+    def test_one_step_per_iteration_with_phases_nested(self, traced_run):
+        tracer, _ = traced_run
+        spans = tracer.spans()
+        chain = self._ancestors(spans)
+        steps = [s for s in spans if s.name == "step"]
+        admits = [s for s in spans if s.name == "admit"]
+        # every iteration admits (or finds nothing to admit) first
+        assert steps and len(admits) == len(steps)
+        assert all(s.parent is None for s in steps)
+        for name in ("admit", "sample", "retire", "report", "decode_tick",
+                     "prefill", "write_row", "wait"):
+            found = [s for s in spans if s.name == name]
+            assert found, name
+            assert all("step" in chain(s) for s in found), name
+        # the run's report ends its last iteration
+        (report,) = [s for s in spans if s.name == "report"]
+        assert report.parent == max(steps, key=lambda s: s.t0).sid
+        # each device wait is inside the step call that dispatched it
+        for w in (s for s in spans if s.name == "wait"):
+            assert chain(w)[0] in ("prefill", "decode_tick",
+                                   "prefill_chunk")
+
+    def test_decode_tick_carries_context_and_pool_length(self, traced_run):
+        tracer, eng = traced_run
+        ticks = [s for s in tracer.spans() if s.name == "decode_tick"]
+        for s in ticks:
+            assert s.attrs["pool_len"] == s.attrs["bucket"]
+            assert 0 < s.attrs["ctx_tokens"] <= \
+                s.attrs["slots"] * s.attrs["pool_len"]
+        # a tick that makes a request's token k (k >= 1; token 0 comes
+        # from its prefill) reads its prompt and k tokens: requests
+        # (4, 3), (7, 2), (5, 4), (3, 2) read 5+6, 8, 6+7+8, 4
+        assert sum(s.attrs["ctx_tokens"] for s in ticks) == 44
+
+    def test_chunked_prefill_phases(self):
+        from repro.serve import ServeEngine
+        from repro.tuner import TuningCache
+
+        tracer = Tracer()
+        eng = ServeEngine("smollm-135m", slots=2, max_len=64, reduced=True,
+                          tracer=tracer, tuning_cache=TuningCache(path=None),
+                          prefill_chunk=8, verbose=False)
+        eng.submit(list(range(1, 20)), max_new_tokens=3)
+        eng.run()
+        spans = tracer.spans()
+        chain = self._ancestors(spans)
+        chunks = [s for s in spans if s.name == "prefill_chunk"]
+        assert len(chunks) == 3                       # 19 tokens, 8 a chunk
+        waits = [s for s in spans if s.name == "wait"
+                 and chain(s)[0] == "prefill_chunk"]
+        assert len(waits) == len(chunks)
+        (row,) = [s for s in spans if s.name == "write_row"]
+        assert chain(row) == ["step"] and row.attrs["prompt_len"] == 19
+        # the first token is sampled off the last chunk, the other two
+        # after decode ticks that read 19 + 1 and 19 + 2 positions
+        ticks = [s for s in spans if s.name == "decode_tick"]
+        assert [t.attrs["ctx_tokens"] for t in ticks] == [20, 21]
+
+
+class TestProfilerAnnotations:
+    """While the JAX profiler records, an enabled tracer's spans enter
+    the profiler trace as ``serve.<name>`` annotations with their
+    attributes; the null tracer writes nothing there."""
+
+    def test_span_in_profiler_trace(self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        tracer = Tracer()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tracer.span("decode_tick", bucket=64, tiles=(32, 128),
+                             paged_decode_block=None) as sp:
+                with tracer.span("wait"):
+                    jax.numpy.ones(8).sum().block_until_ready()
+                sp.set(rows=3)
+            with NULL_TRACER.span("null_span", bucket=1):
+                pass
+            tracer.instant("pool_grow", kv_len=128)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        evs = [ev for plane in ProfileData.from_file(path).planes
+               for line in plane.lines for ev in line.events
+               if ev.name.startswith("serve.")]
+        by_name = {ev.name: ev for ev in evs}
+        assert sorted(by_name) == ["serve.decode_tick", "serve.wait"]
+        assert len(evs) == 2          # no null span, no instant
+        tick = by_name["serve.decode_tick"]
+        stats = {k: v for k, v in tick.stats}
+        assert stats == {"bucket": 64, "tiles": "(32, 128)",
+                         "paged_decode_block": "None", "rows": 3}
+        wait = by_name["serve.wait"]
+        assert tick.start_ns <= wait.start_ns
+        assert wait.end_ns <= tick.end_ns
+        # the records stay on the tracer's clock, attributes as given
+        rec = next(r for r in tracer.spans() if r.name == "decode_tick")
+        assert rec.attrs["tiles"] == (32, 128)
+
+    def test_no_annotation_while_profiler_off(self):
+        from jax.profiler import TraceAnnotation
+
+        assert not TraceAnnotation.is_enabled()
+        tracer = Tracer()
+        with tracer.span("step") as sp:
+            assert sp._ann is None
 
 
 class TestFeedbackLoop:

@@ -22,6 +22,7 @@ actual prefix sharing (``--require-prefix-hits``).
 from __future__ import annotations
 
 import argparse
+import collections
 import os
 import sys
 
@@ -68,6 +69,8 @@ def main(argv=None) -> int:
     print(f"# {args.trace}: {len(spans)} spans, "
           f"arch={meta.get('arch', '?')} hw_meta={meta.get('hw', '?')} "
           f"kv_dtype={meta.get('kv_dtype', 'fp32')}")
+    names = collections.Counter(s.name for s in spans)
+    print("# spans: " + " ".join(f"{k}={v}" for k, v in sorted(names.items())))
     if tracer.counters():
         print("# counters: " + " ".join(
             f"{k}={v:g}" for k, v in sorted(tracer.counters().items())))
