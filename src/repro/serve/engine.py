@@ -751,18 +751,24 @@ class ServeEngine:
                       paged_decode_block=(plan.paged_decode_block
                                           if self.fused_decode else None))
         # the summed context of the rows this tick decodes (each reads
-        # its prompt and every token it has generated)
-        ctx = (sum(r.prompt_len + len(r.generated)
-                   for r in self.scheduler.live
-                   if not r.done and r.rid not in self._prefilling)
-               if self.obs.enabled else None)
+        # its prompt and every token it has generated), and on the paged
+        # pool the pages that covers: what the fused read fetches a layer
+        ctx = pages = None
+        if self.obs.enabled:
+            lens = [r.prompt_len + len(r.generated)
+                    for r in self.scheduler.live
+                    if not r.done and r.rid not in self._prefilling]
+            ctx = sum(lens)
+            if kw:
+                pages = sum(-(-n // self._block_size) for n in lens)
         # the span records the EXECUTED mapping: the fused block_s when
         # the paged read runs fused, the dense decode_block otherwise
         with self.obs.span("decode_tick", bucket=self.pool.kv_len,
                            decode_block=plan.decode_block,
                            paged_decode_block=kw.get("paged_decode_block"),
                            live=len(self.scheduler.live), slots=self.slots,
-                           ctx_tokens=ctx, pool_len=self.pool.kv_len):
+                           ctx_tokens=ctx, pages=pages,
+                           pool_len=self.pool.kv_len):
             t0 = time.perf_counter()
             logits, self._cache = self._decode(self.params,
                                                dict(self._cache),

@@ -797,7 +797,7 @@ def _register_paged_decode():
             desc["dtype_bytes"]))
 
     def cost_model(desc, hw):
-        s, pb, db = desc["s"], desc["page_block"], desc["dtype_bytes"]
+        s, db = desc["s"], desc["dtype_bytes"]
         d, dpad = desc["d"], max(desc["d"], 128)
 
         def cost(block):
@@ -807,11 +807,11 @@ def _register_paged_decode():
             g = ceil_div(s, block)
             padded = g * block
             # k/v streamed once through the table — same bytes as the
-            # gather-free dense sweep; the indirection costs one program
-            # per PAGE (not per block_s chunk), which is what makes tiny
-            # blocks lose here
+            # gather-free dense sweep; one grid step per block_s chunk
+            # (its pages are copied inside the step), so tiny blocks
+            # lose on launches
             return (_roofline_s(padded * 4.0 * d, padded * 2.0 * d * db, hw)
-                    + _launch_s(g * (block // pb), hw))
+                    + _launch_s(g, hw))
 
         return cost
 

@@ -393,6 +393,8 @@ class TestEngineLoopSpans:
         # from its prefill) reads its prompt and k tokens: requests
         # (4, 3), (7, 2), (5, 4), (3, 2) read 5+6, 8, 6+7+8, 4
         assert sum(s.attrs["ctx_tokens"] for s in ticks) == 44
+        # each of those seven rows lies in one 16-position page
+        assert sum(s.attrs["pages"] for s in ticks) == 7
 
     def test_chunked_prefill_phases(self):
         from repro.serve import ServeEngine
@@ -417,6 +419,8 @@ class TestEngineLoopSpans:
         # after decode ticks that read 19 + 1 and 19 + 2 positions
         ticks = [s for s in spans if s.name == "decode_tick"]
         assert [t.attrs["ctx_tokens"] for t in ticks] == [20, 21]
+        # both span two 16-position pages: what the fused read fetches
+        assert [t.attrs["pages"] for t in ticks] == [2, 2]
 
 
 class TestProfilerAnnotations:

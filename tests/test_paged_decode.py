@@ -131,8 +131,9 @@ def test_fused_ref_honours_sliding_window():
 
 def test_pallas_request_on_illegal_geometry_raises():
     """A requested kernel on a cache or ``block_s`` that is not whole
-    pages raises instead of running the reference in its place, while a
-    sliding window still takes the documented reference path."""
+    pages, or on pages that are not whole rows of lanes, raises instead
+    of running the reference in its place, while a sliding window still
+    takes the documented reference path."""
     import jax.numpy as jnp
 
     from repro.kernels.paged_decode_attention import (
@@ -149,12 +150,137 @@ def test_pallas_request_on_illegal_geometry_raises():
             paged_decode_attention(qj, kc, vc, tj, cj, page_block=bs,
                                    block_s=block_s, use_pallas=True,
                                    interpret=True)
+    with pytest.raises(ValueError, match="lanes"):     # 4x2x8 < 128 lanes
+        paged_decode_attention(qj, kj, vj, tj, cj, page_block=4,
+                               block_s=16, use_pallas=True, interpret=True)
     got = paged_decode_attention(qj, kj, vj, tj, cj, page_block=bs,
                                  block_s=32, window=5, use_pallas=True,
                                  interpret=True)
     ref = paged_decode_attention_ref(qj, kj, vj, tj, cj, page_block=bs,
                                      block_s=32, window=5)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+# --------------------------------------------------------------------------- #
+# The fused kernel's live-page sweep: ragged rows, retired rows, dead pages
+# --------------------------------------------------------------------------- #
+
+#: a retired slot: its table is all -1 while its position keeps advancing
+RETIRED = None
+
+
+def _live_page_case(lens, kv, *, t=1024, bs=16, g=3, r=3, d=64, seed=0):
+    """Rows of the given cache lengths (``RETIRED`` for a retired slot)
+    over a ``t``-position physical pool at smollm-135m's head geometry.
+    Live rows lease permuted physical pages, never page 0; the pool holds
+    bf16 values or int8 codes with per-(page, head) f32 scales.  Returns
+    the kernel's arguments and the set of mapped physical pages."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    b, nb = len(lens), t // bs
+    free = list(rng.permutation(np.arange(1, b * nb)))
+    tables = np.full((b, nb), -1, np.int32)
+    clen = np.empty(b, np.int32)
+    for i, n in enumerate(lens):
+        clen[i] = t + 37 if n is RETIRED else n
+        if n is not RETIRED:
+            for j in range(-(-n // bs)):
+                tables[i, j] = free.pop()
+    k = rng.standard_normal((b, t, g, d)).astype(np.float32)
+    v = rng.standard_normal((b, t, g, d)).astype(np.float32)
+    q = rng.standard_normal((b, g, r, d)).astype(np.float32)
+    args = dict(q=q, tables=tables, clen=clen)
+    if kv == "bf16":
+        args.update(k=jnp.asarray(k, jnp.bfloat16),
+                    v=jnp.asarray(v, jnp.bfloat16), ks=None, vs=None)
+    else:
+        for name, x in (("k", k), ("v", v)):
+            blk = x.reshape(b, nb, bs, g, d)
+            sc = np.abs(blk).max(axis=(2, 4)) / 127.0            # (b, nb, g)
+            codes = np.round(blk / sc[:, :, None, :, None])
+            args[name] = codes.reshape(b, t, g, d).astype(np.int8)
+            args[name + "s"] = sc.astype(np.float32)
+    return args, set(tables[tables >= 0].tolist())
+
+
+def _run_fused(args, *, bs, block_s, pallas):
+    import jax.numpy as jnp
+
+    from repro.kernels.paged_decode_attention import (
+        paged_decode_attention_pallas, paged_decode_attention_ref)
+
+    kw = dict(page_block=bs, block_s=block_s,
+              k_scale=None if args["ks"] is None else jnp.asarray(args["ks"]),
+              v_scale=None if args["vs"] is None else jnp.asarray(args["vs"]))
+    if pallas:
+        fn, kw = paged_decode_attention_pallas, dict(kw, interpret=True)
+    else:
+        fn = paged_decode_attention_ref
+    return np.asarray(fn(jnp.asarray(args["q"]), jnp.asarray(args["k"]),
+                         jnp.asarray(args["v"]), jnp.asarray(args["tables"]),
+                         jnp.asarray(args["clen"]), **kw))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("block_s", [64, 1024], ids=["chunked", "whole"])
+@pytest.mark.parametrize("row_len", [1, 23, 32, "block_edge", 1024, RETIRED],
+                         ids=["one", "mid_page", "page_edge", "block_edge",
+                              "full", "retired"])
+def test_fused_kernel_matches_reference_on_ragged_rows(row_len, block_s, kv):
+    """The Pallas kernel (interpret mode) against the blocked reference,
+    with one row of each cache length a pool sees beside a row that
+    spans two fetched sub-blocks, a retired row and a short row.  Live
+    rows match the reference; a retired row writes exact zeros."""
+    bs = 16
+    n = block_s if row_len == "block_edge" else row_len
+    lens = [n, 600, RETIRED, 77]
+    args, _ = _live_page_case(lens, kv, bs=bs)
+    got = _run_fused(args, bs=bs, block_s=block_s, pallas=True)
+    want = _run_fused(args, bs=bs, block_s=block_s, pallas=False)
+    assert np.isfinite(got).all()
+    for i, m in enumerate(lens):
+        if m is RETIRED:
+            np.testing.assert_array_equal(got[i], 0.0)
+        else:
+            np.testing.assert_allclose(got[i], want[i], rtol=1e-5,
+                                       atol=1e-5, err_msg=f"row {i} ({m})")
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_fused_kernel_never_reads_dead_pages(kv):
+    """Every physical page no live row maps (page 0 included, which a
+    -1 entry once aliased) holds NaN: NaN values in a bf16 pool, NaN
+    scales in an int8 one.  The kernel's output for live rows equals the
+    reference's on the clean pool and is finite, so no dead page is ever
+    read into the math, not even to be multiplied by zero."""
+    bs, t = 16, 256
+    lens = [200, RETIRED, 1, 77, t]
+    clean, mapped = _live_page_case(lens, kv, t=t, bs=bs, seed=1)
+    b, nb = len(lens), t // bs
+    dead = [p for p in range(b * nb) if p not in mapped]
+    assert 0 in dead
+    poisoned = dict(clean)
+    if kv == "bf16":
+        for name in ("k", "v"):
+            x = np.asarray(clean[name], np.float32).reshape(b, nb, bs, -1)
+            for p in dead:             # pid -> (pid % B, pid // B)
+                x[p % b, p // b] = np.nan
+            poisoned[name] = x.reshape(clean[name].shape).astype(
+                clean[name].dtype)
+    else:
+        for name in ("ks", "vs"):
+            sc = clean[name].copy()
+            for p in dead:
+                sc[p % b, p // b] = np.nan
+            poisoned[name] = sc
+    got = _run_fused(poisoned, bs=bs, block_s=64, pallas=True)
+    want = _run_fused(clean, bs=bs, block_s=64, pallas=False)
+    assert np.isfinite(got).all()
+    for i, m in enumerate(lens):
+        if m is not RETIRED:
+            np.testing.assert_allclose(got[i], want[i], rtol=1e-5,
+                                       atol=1e-5, err_msg=f"row {i} ({m})")
 
 
 # --------------------------------------------------------------------------- #

@@ -46,16 +46,20 @@ def on_v5e(monkeypatch):
     monkeypatch.setattr(hw, "detect", lambda num_chips=None: V5E)
 
 
-@pytest.fixture(scope="module")
-def router():
+def _router(slots, pool):
     from repro.configs import get_config
     from repro.serve.buckets import BucketRouter, BucketSpec
     from repro.tuner import TuningCache
 
     return BucketRouter(get_config("smollm-135m"),
-                        BucketSpec(max_len=T, min_len=32), slots=B, hw=V5E,
-                        policy=MappingPolicy.TUNED,
+                        BucketSpec(max_len=pool, min_len=32), slots=slots,
+                        hw=V5E, policy=MappingPolicy.TUNED,
                         cache=TuningCache(path=None), page_block=PB)
+
+
+@pytest.fixture(scope="module")
+def router():
+    return _router(B, T)
 
 
 def _assert_kernel_compiles(fn, *shapes):
@@ -63,21 +67,33 @@ def _assert_kernel_compiles(fn, *shapes):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_fused_paged_decode_compiles(one_chip, router, kv):
+#: (slots, pool) of the tests' serving widths and of the chat cell
+CHAT = (64, 2048)
+
+
+@pytest.mark.parametrize("kv,geometry", [
+    ("bf16", (B, T)), ("int8", (B, T)), ("bf16", CHAT), ("int8", CHAT)],
+    ids=["bf16", "int8", "bf16-chat", "int8-chat"])
+def test_fused_paged_decode_compiles(one_chip, kv, geometry):
+    """At the tests' widths, and at the chat cell's 64 slots over a
+    2048-position pool with ``block_s`` as the v5e router resolves it
+    there (the whole pool: one grid step per row, long rows streamed in
+    sub-blocks)."""
     from repro.kernels.paged_decode_attention import \
         paged_decode_attention_pallas
 
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    block_s = router.resolve(router.bucket(T)).paged_decode_block
+    b, t = geometry
+    router = _router(b, t)
+    block_s = router.resolve(router.bucket(t)).paged_decode_block
     cdt = jnp.bfloat16 if kv == "bf16" else jnp.int8
-    args = [s((B, G, R, D), jnp.bfloat16), s((B, T, G, D), cdt),
-            s((B, T, G, D), cdt), s((B, T // PB), jnp.int32),
-            s((B,), jnp.int32)]
+    args = [s((b, G, R, D), jnp.bfloat16), s((b, t, G, D), cdt),
+            s((b, t, G, D), cdt), s((b, t // PB), jnp.int32),
+            s((b,), jnp.int32)]
     if kv == "int8":
-        args += [s((B, T // PB, G), jnp.float32)] * 2
+        args += [s((b, t // PB, G), jnp.float32)] * 2
 
     def fn(q, k, v, tbl, clen, *scales):
         ks, vs = scales if scales else (None, None)

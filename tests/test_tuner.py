@@ -214,6 +214,22 @@ def test_tuned_plan_never_beats_cost_of_seed():
     assert info.cost <= info.seed_cost
 
 
+def test_paged_decode_cost_charges_one_launch_per_chunk():
+    """The fused paged decode runs one grid step per ``block_s`` chunk of
+    a row (its pages are copied inside the step), so the cost model's
+    launch term counts chunks, not pages."""
+    from repro.tuner.dispatch import _launch_s, _roofline_s
+
+    hw = TPU_REGISTRY["tpu_v5e"]
+    desc = {"s": 2048, "d": 64, "page_block": 16, "max_blocks_per_row": 128,
+            "dtype": "bfloat16", "dtype_bytes": 2}
+    cost = KERNEL_REGISTRY["paged_decode"].cost_model(desc, hw)
+    for block in (16, 256, 2048):
+        chunks = 2048 // block
+        streamed = _roofline_s(2048 * 4.0 * 64, 2048 * 2.0 * 64 * 2, hw)
+        assert cost(block) == pytest.approx(streamed + _launch_s(chunks, hw))
+
+
 def test_tuned_fallback_without_cost_model():
     """A kernel with no cost model returns the Eq. 1 seed, cached, no error."""
     spec = KERNEL_REGISTRY["vecadd"]
