@@ -7,9 +7,11 @@ position the reading is how far the served token's reference logit lies
 below the reference's best there.  The number compared is the widest
 such gap over the sample, in logits.
 
-The control (``control_gaps``) puts the reference in the program's
-place at float8: at the same positions of the same sequences it reads
-the gap of the token that the fp8 forward puts first.
+The reference is the ``logits`` of the configuration's family file
+(``bench/families/<family>.py``).  The control (``control_gaps``) puts
+the reference in the program's place at float8: at the same positions
+of the same sequences it reads the gap of the token that the fp8
+forward puts first.
 """
 
 from __future__ import annotations
@@ -17,8 +19,6 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
-
-from harness import reference
 
 
 @dataclasses.dataclass
@@ -53,26 +53,26 @@ def _positions(s: Served):
     return seq, want
 
 
-def served_gaps(params, sizes: dict, items: list) -> np.ndarray:
+def served_gaps(family, params, sizes: dict, items: list) -> np.ndarray:
     """Reference gap of every served token of ``items``."""
     out = []
     for s in items:
         seq, want = _positions(s)
-        ref = reference.logits(params, sizes, seq, want)
+        ref = family.logits(params, sizes, seq, want)
         tok = np.asarray(s.tokens)
         out.append(ref.max(axis=1) - ref[np.arange(len(tok)), tok])
     return np.concatenate(out) if out else np.zeros(0)
 
 
-def control_gaps(params, sizes: dict, items: list,
+def control_gaps(family, params, sizes: dict, items: list,
                  quant: str = "fp8") -> np.ndarray:
     """Reference gap of the token the ``quant`` forward puts first, at
     every served position of ``items``."""
     out = []
     for s in items:
         seq, want = _positions(s)
-        ref = reference.logits(params, sizes, seq, want)
-        low = reference.logits(params, sizes, seq, want, quant=quant)
+        ref = family.logits(params, sizes, seq, want)
+        low = family.logits(params, sizes, seq, want, quant=quant)
         top = low.argmax(axis=1)
         out.append(ref.max(axis=1) - ref[np.arange(len(top)), top])
     return np.concatenate(out) if out else np.zeros(0)

@@ -1,14 +1,12 @@
-"""The plain reference: the published decoder (Llama / Qwen3 dense
-layers) in float32 jax.numpy at the highest matmul precision, with no
-kernel, cache, batching or chunking.  It imports nothing of the program.
+"""What every family's plain reference shares (``bench/families/<family>.py``
+composes its layers from these): float32 jax.numpy at the highest
+matmul precision, with no kernel, cache, batching or chunking.  It
+imports nothing of the program.
 
-It reads the weights as a dict keyed by the program's leaf names
-(``embed/tok``, ``blocks/attn/wq`` ...), stacked over layers, and
-upcasts one layer at a time inside a scan, so float32 copies of all the
-weights never exist at once.  Two conventions are the program's and are
-followed here: RMSNorm gains are stored as offsets from 1 (the gain is
-``1 + g``), and rotary embedding rotates the two halves of each head
-(``rotate_half``, as in the published Llama and Qwen3 code).
+Two conventions are the program's and are followed here: RMSNorm gains
+are stored as offsets from 1 (the gain is ``1 + g``), and rotary
+embedding rotates the two halves of each head (``rotate_half``, as in
+the published Llama and Qwen3 code).
 
 ``quant="fp8"`` is the control: every matmul's weights (per output
 channel) and inputs (per row) are rounded to float8 e4m3 with absmax
@@ -42,18 +40,18 @@ def _fp8(x, axis):
     return (x / s).astype(jnp.float8_e4m3fn).astype(F32) * s
 
 
-def _mm(spec, x, w, quant, x_axis, w_axis):
+def mm(spec, x, w, quant, x_axis, w_axis):
     if quant == "fp8":
         x, w = _fp8(x, x_axis), _fp8(w, w_axis)
     return jnp.einsum(spec, x, w, precision=HI)
 
 
-def _rms(x, g, eps):
+def rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
                              + eps) * (1.0 + g)
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x (S, n, hd): rotate the two halves by position-dependent angles."""
     half = x.shape[-1] // 2
     freqs = theta ** (-jnp.arange(half, dtype=F32) / half)
@@ -63,7 +61,7 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
-def _attention(q, k, v):
+def attention(q, k, v):
     """Causal GQA: q (S, G, R, hd), k/v (S, G, hd) -> (S, G, R, hd),
     swept in blocks of queries."""
     s, g, r, hd = q.shape
@@ -83,64 +81,22 @@ def _attention(q, k, v):
     return out.reshape(s, g, r, hd)
 
 
-def _forward(params, tokens, want, *, sz, quant):
-    L, d = sz["num_hidden_layers"], sz["hidden_size"]
-    h, g, hd = (sz["num_attention_heads"], sz["num_key_value_heads"],
-                sz["head_dim"])
-    eps, theta = float(sz["rms_norm_eps"]), float(sz["rope_theta"])
-    qk_norm = bool(sz.get("qk_norm", False))
-    tok = params["embed"]["tok"]
-    x = tok[tokens].astype(F32)
-    pos = jnp.arange(tokens.shape[0])
-
-    def layer(x, p):
-        p = jax.tree.map(lambda a: a.astype(F32), p)
-        a = p["attn"]
-        hn = _rms(x, p["ln1"], eps)
-        q = _mm("sd,dhk->shk", hn, a["wq"], quant, -1, 0)
-        k = _mm("sd,dgk->sgk", hn, a["wk"], quant, -1, 0)
-        v = _mm("sd,dgk->sgk", hn, a["wv"], quant, -1, 0)
-        if qk_norm:
-            q = _rms(q, a["q_norm"], eps)
-            k = _rms(k, a["k_norm"], eps)
-        q, k = _rope(q, pos, theta), _rope(k, pos, theta)
-        o = _attention(q.reshape(-1, g, h // g, hd), k, v)
-        o = o.reshape(-1, h, hd)
-        x = x + _mm("shk,hkd->sd", o, a["wo"], quant, (-2, -1), (0, 1))
-        hn = _rms(x, p["ln2"], eps)
-        m = p["mlp"]
-        gate = _mm("sd,df->sf", hn, m["w_gate"], quant, -1, 0)
-        up = _mm("sd,df->sf", hn, m["w_up"], quant, -1, 0)
-        x = x + _mm("sf,fd->sd", jax.nn.silu(gate) * up, m["w_down"],
-                    quant, -1, 0)
-        return x, None
-
-    x, _ = jax.lax.scan(layer, x, params["blocks"])
-    xw = _rms(x[want], params["ln_f"].astype(F32), eps)
-    if "unembed" in params["embed"]:
-        return _mm("sd,dv->sv", xw, params["embed"]["unembed"].astype(F32),
-                   quant, -1, 0)
-    return _mm("sd,vd->sv", xw, tok.astype(F32), quant, -1, -1)
-
-
 @functools.lru_cache(maxsize=None)
-def _compiled(sz_items: tuple, quant):
-    return jax.jit(functools.partial(_forward, sz=dict(sz_items),
-                                     quant=quant))
+def _compiled(forward, sz_items: tuple, quant):
+    return jax.jit(functools.partial(forward, sz=dict(sz_items), quant=quant))
 
 
-def logits(params, sizes: dict, tokens, want, quant=None) -> np.ndarray:
-    """float32 logits (len(want), vocab) of the sequence ``tokens`` at
-    the positions ``want``."""
-    keys = ("num_hidden_layers", "hidden_size", "num_attention_heads",
-            "num_key_value_heads", "head_dim", "rms_norm_eps", "rope_theta",
-            "qk_norm")
-    sz = tuple((k, sizes.get(k)) for k in keys)
+def run(forward, sz_items: tuple, params, tokens, want,
+        quant=None) -> np.ndarray:
+    """``forward(params, tokens, want, sz=..., quant=...)`` jitted once
+    per sizes and precision, over ``tokens`` padded to ``PAD_TO`` and
+    ``want`` to ``WANT_TO``: float32 logits (len(want), vocab)."""
     n = len(tokens)
     padded = np.zeros(-(-n // PAD_TO) * PAD_TO, np.int32)
     padded[:n] = tokens
     w = np.asarray(want, np.int32)
     wp = np.full(-(-len(w) // WANT_TO) * WANT_TO, w[-1], np.int32)
     wp[:len(w)] = w
-    out = _compiled(sz, quant)(params, jnp.asarray(padded), jnp.asarray(wp))
+    out = _compiled(forward, sz_items, quant)(params, jnp.asarray(padded),
+                                              jnp.asarray(wp))
     return np.asarray(out, np.float32)[:len(w)]

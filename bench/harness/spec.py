@@ -6,6 +6,10 @@ per-layer metric sits in a file of its own, found by the name that
 ``BENCHMARK.json`` gives it:
 
     bench/configs/<config>.json     sizes as run, with their source
+    bench/families/<family>.py      what the harness knows of a model
+                                    family (the config's "family"): its
+                                    published keys, plain reference,
+                                    weight fan-in and work counts
     bench/traffic/<traffic>.json    parameters for a generator module
                                     named in the file (bench/traffic/)
     bench/limits/<cell>.json        the limits that decide ``correct``
@@ -15,6 +19,7 @@ per-layer metric sits in a file of its own, found by the name that
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import sys
 from pathlib import Path
@@ -24,26 +29,7 @@ from typing import Optional
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = ROOT / "bench"
 
-#: configuration-file keys (published names) -> the program's
-#: ``ModelConfig`` fields
-HF_TO_MODEL = {
-    "num_hidden_layers": "num_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "head_dim": "head_dim",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-}
-
-#: tiny widths for the CPU rehearsal (never a device number)
-REHEARSAL_SIZES = {"num_hidden_layers": 2, "hidden_size": 128,
-                   "num_attention_heads": 4, "num_key_value_heads": 2,
-                   "head_dim": 32, "intermediate_size": 256,
-                   "vocab_size": 512}
+#: the CPU rehearsal's serving settings (its widths are the family's)
 REHEARSAL_SERVE = {"slots": 4, "max_len": 256}
 
 
@@ -56,6 +42,7 @@ class Cell:
     config_name: str
     traffic_name: str
     config: dict          # bench/configs/<config>.json
+    family: object        # bench/families/<family>.py, loaded
     traffic: dict         # bench/traffic/<traffic>.json
     limits: dict          # bench/limits/<cell>.json
     end_to_end: list      # metric entries this cell reports at --trace 0
@@ -83,10 +70,11 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     configs = {c["name"]: c for c in spec["configs"]}
     conf_entry = configs[w["config"]]
     bench = root / "bench"
+    config = _read_json(root / conf_entry["file"])
     return Cell(
         name=name, chips=int(w["chips"]),
         config_name=w["config"], traffic_name=w["traffic"],
-        config=_read_json(root / conf_entry["file"]),
+        config=config, family=family(config, root),
         traffic=_read_json(bench / "traffic" / f"{w['traffic']}.json"),
         limits=_read_json(bench / "limits" / f"{name}.json"),
         end_to_end=[m for m in spec["end_to_end"] if _reports(m, name)],
@@ -94,12 +82,28 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
         run_seconds=int(spec["run_seconds"]))
 
 
-def sizes(config: dict, rehearse: bool = False) -> dict:
+def family(config: dict, root: Path = ROOT):
+    """The family file that ``config["family"]`` names; raises
+    ``FileNotFoundError`` naming the path where there is none."""
+    path = root / "bench" / "families" / f"{config['family']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"configuration {config.get('name')!r} is of family "
+            f"{config['family']!r}, but {path} does not exist")
+    return _load_family(path)
+
+
+@functools.lru_cache(maxsize=None)
+def _load_family(path: Path):
+    return load_module(path, f"bench_family_{path.stem}")
+
+
+def sizes(config: dict, rehearse: bool = False, root: Path = ROOT) -> dict:
     """The configuration's published-name sizes as run (the rehearsal
-    swaps in tiny widths)."""
+    swaps in its family's tiny widths)."""
     out = dict(config)
     if rehearse:
-        out.update(REHEARSAL_SIZES)
+        out.update(family(config, root).REHEARSAL_SIZES)
     return out
 
 
@@ -110,20 +114,13 @@ def serve_settings(config: dict, rehearse: bool = False) -> dict:
     return out
 
 
-def model_config(config: dict, rehearse: bool = False):
-    """The program's ``ModelConfig`` for a configuration file: every
-    size comes from the file, nothing from the program's registry."""
-    from repro.configs.base import ModelConfig
-
-    s = sizes(config, rehearse)
-    if s["hidden_act"] != "silu":
-        raise ValueError(f"unsupported hidden_act {s['hidden_act']!r}")
-    kw = {field: s[key] for key, field in HF_TO_MODEL.items()}
-    return ModelConfig(name=config["name"] + ("-rehearsal" if rehearse
-                                              else ""),
-                       family=config["family"], mlp_act="swiglu",
-                       qk_norm=bool(s.get("qk_norm", False)),
-                       dtype=s["torch_dtype"], **kw)
+def model_config(config: dict, rehearse: bool = False, root: Path = ROOT):
+    """The program's ``ModelConfig`` for a configuration file, by its
+    family: every size comes from the file, nothing from the program's
+    registry."""
+    name = config["name"] + ("-rehearsal" if rehearse else "")
+    return family(config, root).model_config(sizes(config, rehearse, root),
+                                             name)
 
 
 def metric_module_path(name: str, root: Path = ROOT) -> Path:

@@ -7,14 +7,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-from harness import work
-
 
 @dataclasses.dataclass
 class RunView:
     cell_name: str
     seconds: float
-    shapes: work.Shapes
+    shapes: object                # the family's work counts (shapes())
     slots: int                    # rows of the engine's decode batch
     peaks: object                 # harness.peaks.Peaks (None in rehearsal)
     window: object                # harness.driver.Window

@@ -24,10 +24,6 @@ import jax.numpy as jnp
 NORM_LEAVES = frozenset({"ln1", "ln2", "ln_f", "q_norm", "k_norm"})
 NORM_STD = 0.1
 EMBED_STD = 0.02
-#: input axes of each matrix (after the leading layer axis, if stacked):
-#: the output projection contracts heads and head_dim; every other
-#: matrix its first axis
-FAN_IN_AXES = {"wo": 2}
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -40,22 +36,21 @@ def leaf_name(path) -> str:
     return str(getattr(path[-1], "key", path[-1]))
 
 
-def _std(path, shape) -> float:
+def _std(path, shape, fan_in) -> float:
+    """``fan_in(name, shape)`` is the family's (``bench/families/``), given
+    the leaf's shape after the leading layer axis, if stacked."""
     name = leaf_name(path)
     if name in NORM_LEAVES:
         return NORM_STD
     if name == "tok":
         return EMBED_STD
     stacked = any(getattr(p, "key", None) == "blocks" for p in path)
-    lead = 1 if stacked else 0
-    fan_in = 1
-    for n in shape[lead:lead + FAN_IN_AXES.get(name, 1)]:
-        fan_in *= n
-    return fan_in ** -0.5
+    return fan_in(name, shape[1:] if stacked else shape) ** -0.5
 
 
-def make_params(init_fn, seed: int):
-    """Weights shaped like ``init_fn(key)``'s tree, drawn from ``seed``."""
+def make_params(init_fn, seed: int, fan_in):
+    """Weights shaped like ``init_fn(key)``'s tree, drawn from ``seed``,
+    each matrix scaled by the family's ``fan_in``."""
     abstract = jax.eval_shape(init_fn, jax.random.key(0))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(abstract)
 
@@ -63,7 +58,7 @@ def make_params(init_fn, seed: int):
     def build(key):
         vals = []
         for i, (path, leaf) in enumerate(leaves):
-            std = _std(path, leaf.shape)
+            std = _std(path, leaf.shape, fan_in)
             z = jax.random.normal(jax.random.fold_in(key, i), leaf.shape,
                                   jnp.float32)
             vals.append((z * std).astype(leaf.dtype))

@@ -9,7 +9,7 @@ seed, builds the program's serving engine for the cell's configuration,
 warms every shape the cell's traffic will use, serves the traffic on
 the wall clock for ``--seconds``, drains the requests due in that
 window, then checks a seeded sample of what was served against the
-plain reference in ``bench/harness/reference.py``.
+plain reference of the configuration's family (``bench/families/``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics
@@ -93,7 +93,8 @@ def build(cell, seed: int, rehearse: bool, trace: bool, clock):
 
     cfg = spec.model_config(cell.config, rehearse)
     serve = spec.serve_settings(cell.config, rehearse)
-    params = weights.make_params(build_model(cfg).init, seed)
+    params = weights.make_params(build_model(cfg).init, seed,
+                                 cell.family.fan_in)
     jax.block_until_ready(params)
     pool = {"bfloat16": "fp32", "int8": "int8"}[serve["pool_dtype"]]
     engine = ServeEngine(cfg, slots=serve["slots"], max_len=serve["max_len"],
@@ -123,7 +124,7 @@ def run(args) -> int:
 
         peaks = peaks_for(dev.device_kind)
 
-    from harness import check, driver, trace, work
+    from harness import check, driver, trace
     from harness.view import RunView
 
     sizes = spec.sizes(cell.config, args.rehearse)
@@ -178,7 +179,7 @@ def run(args) -> int:
         ex = trace.extract(trace.find_xplane(prof_dir))
         shutil.rmtree(prof_dir, ignore_errors=True)
     view = RunView(cell_name=cell.name, seconds=args.seconds,
-                   shapes=work.Shapes.from_sizes(sizes),
+                   shapes=cell.family.shapes(sizes),
                    slots=serve["slots"], peaks=peaks,
                    window=win, setup_s=setup_s,
                    engine_metrics=engine.metrics,
@@ -203,7 +204,7 @@ def run(args) -> int:
     gc.collect()
     items = check.sample(done, args.seed, SAMPLE_TOKENS, SAMPLE_REQUESTS)
     t_ref, c_ref = time.perf_counter(), counter.mark()
-    gaps = check.served_gaps(params, sizes, items)
+    gaps = check.served_gaps(cell.family, params, sizes, items)
     log(f"reference check: {time.perf_counter() - t_ref:.3f} s, "
         f"{counter.describe(c_ref)}")
     limits = cell.limits["rehearsal"] if args.rehearse else cell.limits

@@ -1,22 +1,24 @@
 """FLOP and byte counts against values worked out by hand at both
-configurations' shapes."""
+configurations' shapes, as the dense family file counts them."""
 
 import json
 
 import pytest
 
-from harness import work
+from harness import spec, work
 from harness.spec import BENCH
+
+DENSE = spec.family({"name": "qwen3-8b-9l", "family": "dense"})
 
 
 def shapes(name):
     conf = json.loads((BENCH / "configs" / f"{name}.json").read_text())
-    return work.Shapes.from_sizes(conf)
+    return spec.family(conf).shapes(conf)
 
 
 #: Qwen3-8B's published widths (hf:Qwen/Qwen3-8B) at 9 of its 36 layers
-QWEN3_9L = work.Shapes(layers=9, d=4096, heads=32, kv_heads=8, head_dim=128,
-                       d_ff=12288, vocab=151936)
+QWEN3_9L = DENSE.Shapes(layers=9, d=4096, heads=32, kv_heads=8, head_dim=128,
+                        d_ff=12288, vocab=151936)
 
 
 def test_smollm_counts_by_hand():

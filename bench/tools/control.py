@@ -8,7 +8,8 @@ drained, then on the same seeded sample of served requests
   reference logit lies below the reference's best (what ``run.py``
   compares), and
 * the control's reading: the same gap for the token that the reference
-  computed in float8 (``reference.py``, ``quant="fp8"``) puts first.
+  computed in float8 (the family's ``logits``, ``quant="fp8"``) puts
+  first.
 
     python3 bench/tools/control.py --workload smollm-135m.chat \
         --seeds 1,2,3 --seconds 10
@@ -61,7 +62,8 @@ def main() -> int:
     init = build_model(cfg).init
     drv = driver.Driver(engine, clock, counter)
     for k, seed in enumerate(seeds):
-        params = engine.params if k == 0 else weights.make_params(init, seed)
+        params = engine.params if k == 0 else weights.make_params(
+            init, seed, cell.family.fan_in)
         engine.params = params
         sched = mix_mod.schedule(mix, seed, args.seconds, sizes["vocab_size"])
         if k == 0:
@@ -72,8 +74,8 @@ def main() -> int:
                 for r in win.recs if r.done]
         items = check.sample(done, seed, bench_run.SAMPLE_TOKENS,
                              bench_run.SAMPLE_REQUESTS)
-        prog = check.served_gaps(params, sizes, items)
-        ctrl = check.control_gaps(params, sizes, items)
+        prog = check.served_gaps(cell.family, params, sizes, items)
+        ctrl = check.control_gaps(cell.family, params, sizes, items)
         print(json.dumps({
             "seed": seed, "requests": len(items), "tokens": int(prog.size),
             "attempted": len(win.recs),
