@@ -11,8 +11,8 @@ import dataclasses
 import math
 from typing import Optional
 
-__all__ = ["ModelConfig", "ShapeConfig", "SHAPES", "register", "get_config",
-           "list_configs"]
+__all__ = ["ModelConfig", "ExpertShare", "ShapeConfig", "SHAPES", "register",
+           "get_config", "list_configs"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,6 +79,12 @@ class ModelConfig:
         return self.d_inner // self.ssm_head_dim
 
     @property
+    def held_experts(self) -> range:
+        """Ids of the experts this chip holds: every one (``ExpertShare``
+        holds a share)."""
+        return range(self.moe_experts)
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -93,7 +99,7 @@ class ModelConfig:
             mlp = 2 * d * self.d_ff
         if self.family == "moe":
             e_mlp = 3 * d * self.moe_dff
-            mlp = (self.moe_experts + self.moe_shared_experts) * e_mlp \
+            mlp = (len(self.held_experts) + self.moe_shared_experts) * e_mlp \
                 + d * self.moe_experts          # router
         per_layer = attn + mlp + 2 * d
         if self.family == "ssm":
@@ -127,7 +133,7 @@ class ModelConfig:
             return self.n_params()
         d = self.d_model
         e_mlp = 3 * d * self.moe_dff
-        inactive = (self.moe_experts - self.moe_topk) * e_mlp
+        inactive = max(len(self.held_experts) - self.moe_topk, 0) * e_mlp
         return self.n_params() - self.num_layers * inactive
 
     def reduced(self) -> "ModelConfig":
@@ -154,6 +160,40 @@ class ModelConfig:
             encoder_tokens=min(self.encoder_tokens, 24),
             prefix_tokens=min(self.prefix_tokens, 8),
         )
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertShare(ModelConfig):
+    """A MoE config of which this chip holds one share of each layer's
+    experts, as one rank of an expert-parallel deployment holds them:
+    ``moe_held`` experts from ``moe_first`` on.  The router keeps all
+    ``moe_experts`` outputs and its ``moe_topk``; the experts held
+    elsewhere add nothing here."""
+
+    moe_first: int = 0
+    moe_held: int = 0
+
+    def __post_init__(self):
+        super().__post_init__()
+        if not (self.family == "moe" and self.moe_held > 0
+                and 0 <= self.moe_first
+                and self.moe_first + self.moe_held <= self.moe_experts):
+            raise ValueError(
+                f"{self.name}: experts {self.moe_first}.."
+                f"{self.moe_first + self.moe_held - 1} are not a share of "
+                f"{self.moe_experts} {self.family} experts")
+
+    @classmethod
+    def of(cls, cfg: ModelConfig, first: int, held: int) -> "ExpertShare":
+        """``cfg`` with ``held`` of its experts, from ``first`` on, held
+        here."""
+        return cls(**{f.name: getattr(cfg, f.name)
+                      for f in dataclasses.fields(ModelConfig)},
+                   moe_first=first, moe_held=held)
+
+    @property
+    def held_experts(self) -> range:
+        return range(self.moe_first, self.moe_first + self.moe_held)
 
 
 @dataclasses.dataclass(frozen=True)

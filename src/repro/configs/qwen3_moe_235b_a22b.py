@@ -1,4 +1,5 @@
-"""qwen3-moe-235b-a22b — 128-expert top-8 MoE [hf:Qwen/Qwen3-30B-A3B family]."""
+"""qwen3-moe-235b-a22b — 94 layers, each of 128 experts routed top-8, q/k
+norm, untied head [hf:Qwen/Qwen3-235B-A22B]."""
 from repro.configs.base import ModelConfig, register
 
 CONFIG = register(ModelConfig(

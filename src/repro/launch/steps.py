@@ -237,7 +237,8 @@ def make_chunk_prefill_step(model: Model, plan: Plan,
     STATIC like the whole-prompt path; the chunk width C and row-cache
     length are static by shape, while the start offset (``cache["pos"]``)
     and ``n_valid`` stay traced — so the compile set is bounded by the
-    (C, cache_len, tiles) lattice, not by prompt lengths."""
+    (C, cache_len, tiles) lattice, not by prompt lengths.  A config
+    with experts also returns its expert layer's load (L, held) int32."""
     ctx = make_ctx(plan)
     ctx.flags.update(flags or {})
 
@@ -260,18 +261,21 @@ def make_decode_step(model: Model, plan: Plan,
     array — live tables change every admission) + ``page_block`` (static)
     switch the KV caches to the physical block-table layout;
     ``paged_decode_block`` (static, router-tuned) fuses the table read
-    into the attention sweep itself."""
+    into the attention sweep itself.  A config with experts takes
+    ``rows`` (B,) bool, the rows in flight, and also returns its expert
+    layer's load (L, held) int32 over them."""
     ctx = make_ctx(plan)
     ctx.flags.update(flags or {})
 
     def decode_step(params, cache, tokens, decode_block=None,
                     page_tables=None, page_block=None,
-                    paged_decode_block=None):
+                    paged_decode_block=None, rows=None):
         with jax.named_scope("serve.decode_step"):
             return model.decode_step(params, cache, tokens, ctx=ctx,
                                      decode_block=decode_block,
                                      page_tables=page_tables,
                                      page_block=page_block,
-                                     paged_decode_block=paged_decode_block)
+                                     paged_decode_block=paged_decode_block,
+                                     rows=rows)
 
     return decode_step

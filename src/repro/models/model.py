@@ -7,6 +7,7 @@ Everything the launch layer needs:
   loss, aux = m.loss(params, batch, ctx=ctx)
   logits, cache = m.prefill(params, batch, max_len, ctx=ctx)
   logits, cache = m.decode_step(params, cache, tokens, ctx=ctx)
+  # moe serving steps also return the expert layer's load (L, held)
 
 ``input_specs`` builds the allocation-free ShapeDtypeStruct stand-ins for
 every (shape x kind) cell, including the stub modality inputs (VLM patch
@@ -89,13 +90,16 @@ class Model:
         router's bucket-tuned flash (block_q, block_k) — parameterizes
         the EXECUTED attention mapping for the attention families;
         ``None`` (and the attention-free ssm family) keeps the
-        hardware-agnostic GSPMD path byte-for-byte."""
+        hardware-agnostic GSPMD path byte-for-byte.  A forward that
+        returns its cache is serving's prefill: its expert layers run
+        dropless (``moe.moe_dropless``)."""
         cfg, f = self.cfg, self.cfg.family
         tokens = batch["tokens"]
         if f in ("dense", "moe"):
             return tf_mod.forward(params, tokens, cfg, remat=remat,
                                   return_cache=return_cache,
-                                  prefill_tiles=prefill_tiles, ctx=ctx)
+                                  prefill_tiles=prefill_tiles,
+                                  dropless=return_cache, ctx=ctx)
         if f == "vlm":
             return tf_mod.forward(params, tokens, cfg, remat=remat,
                                   prefix_embeds=batch["patches"],
@@ -240,11 +244,14 @@ class Model:
         ``n_valid`` (traced scalar) is the number of real tokens in the
         chunk; only tail chunks carry padding.  Returns
         (logits (B, C, V), updated cache) — the caller reads the true
-        last-token logits at ``[:, n_valid - 1]`` of the final chunk.
+        last-token logits at ``[:, n_valid - 1]`` of the final chunk —
+        and for moe also the expert layer's load (L, held) over the
+        ``n_valid`` real tokens.
         """
         cfg, f = self.cfg, self.cfg.family
         if f in ("dense", "moe"):
             return tf_mod.chunk_prefill_step(params, cache, tokens, cfg,
+                                             n_valid=n_valid,
                                              prefill_tiles=prefill_tiles,
                                              ctx=ctx)
         if f not in ("ssm", "hybrid"):
@@ -270,7 +277,7 @@ class Model:
     def decode_step(self, params, cache, tokens, *, ctx: ShardCtx = NO_SHARD,
                     decode_block: Optional[int] = None,
                     page_tables=None, page_block: Optional[int] = None,
-                    paged_decode_block: Optional[int] = None):
+                    paged_decode_block: Optional[int] = None, rows=None):
         """One decode step.  ``decode_block`` is the bucket-tuned
         decode-attention cache block resolved by the serving router; it
         selects the *executed* attention sweep (Pallas kernel or blocked
@@ -280,12 +287,16 @@ class Model:
         the physical block-table layout (serving's paged pool);
         ``paged_decode_block`` (the router's tuned fused ``block_s``)
         makes the sweep consume the tables directly instead of gathering
-        a logical view first."""
+        a logical view first.  moe: ``rows`` (B,) bool marks the rows in
+        flight, and the step also returns the expert layer's load
+        (L, held) over them (``transformer.decode_step``)."""
         cfg, f = self.cfg, self.cfg.family
         kw = dict(ctx=ctx, decode_block=decode_block,
                   page_tables=page_tables, page_block=page_block,
                   paged_decode_block=paged_decode_block)
         if f in ("dense", "moe", "vlm"):
+            if f == "moe":
+                kw["rows"] = rows
             return tf_mod.decode_step(params, cache, tokens, cfg, **kw)
         if f == "ssm":
             return ssm_mod.ssm_decode(params, cache, tokens, cfg, **kw)

@@ -1,4 +1,5 @@
-"""Mixture-of-Experts layer: fine-grained experts, capacity routing, EP.
+"""Mixture-of-Experts layer: fine-grained experts, capacity routing, EP,
+and the dropless layer that serving runs.
 
 Dispatch is **sort-based** (argsort expert ids -> position-in-expert ->
 scatter into (E, C, d) buffers), not the one-hot einsum some frameworks
@@ -13,6 +14,13 @@ zero — the MoE instance of the paper's exact-fit regime.
 
 Experts are sharded over the ``model`` axis (EP); GSPMD materializes the
 token all-to-all from the sharding annotations.
+
+Serving runs ``moe_dropless`` instead (the step decides, not a flag):
+no capacity, so no (token, expert) pair is ever dropped and a token's
+output never depends on the other tokens of its batch.  It computes the
+part of the result that this chip's experts give (``cfg.held_experts``,
+one rank's share of an expert-parallel layer) with one grouped matmul
+per weight over the pairs routed to them.
 """
 
 from __future__ import annotations
@@ -29,9 +37,10 @@ from repro.models.layers import ParamSpec, ShardCtx
 
 
 def moe_specs(cfg: ModelConfig) -> dict:
-    d, e, ff = cfg.d_model, cfg.moe_experts, cfg.moe_dff
+    """The router spans every expert; the expert weights are the held ones."""
+    d, e, ff = cfg.d_model, len(cfg.held_experts), cfg.moe_dff
     s = {
-        "router": ParamSpec((d, e), ("embed", "experts_r")),
+        "router": ParamSpec((d, cfg.moe_experts), ("embed", "experts_r")),
         "w_gate": ParamSpec((e, d, ff), ("experts", "embed", None)),
         "w_up": ParamSpec((e, d, ff), ("experts", "embed", None)),
         "w_down": ParamSpec((e, ff, d), ("experts", None, "embed")),
@@ -115,6 +124,9 @@ def moe_mlp(
     """
     b, s, d = h.shape
     e, k = cfg.moe_experts, cfg.moe_topk
+    if len(cfg.held_experts) != e:
+        raise ValueError(f"{cfg.name}: capacity routing needs every expert "
+                         f"held; serving runs moe_dropless")
     t = b * s
     groups = int(ctx.flag("moe_groups", 1))
     while t % groups:
@@ -171,3 +183,59 @@ def moe_mlp(
         y = y.reshape(t, d) + jnp.einsum("tf,fd->td", hh, sp["w_down"])
 
     return y.reshape(b, s, d), aux.astype(jnp.float32)
+
+
+def moe_dropless(params: dict, h: jax.Array, cfg: ModelConfig,
+                 valid: Optional[jax.Array] = None
+                 ) -> tuple[jax.Array, jax.Array]:
+    """The serving expert layer: ``h`` (..., d) -> (output (..., d), load
+    (held,) int32).
+
+    Router logits in float32 over all ``moe_experts``, softmax, top-k,
+    gates renormalised over the k.  The (token, expert) pairs whose expert
+    this chip holds, of the tokens ``valid`` marks (default: all), are
+    sorted by expert and run through one grouped matmul per weight
+    (``jax.lax.ragged_dot``); every other pair sorts after them and adds
+    nothing.  Each pair's output, times its gate, is added back to its
+    token.  ``load`` counts the pairs on each held expert.  A shared
+    expert, which every chip computes alike, is added whole."""
+    shape, d = h.shape, h.shape[-1]
+    x = h.reshape(-1, d)
+    t, k = x.shape[0], cfg.moe_topk
+    held = cfg.held_experts
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        params["router"].astype(jnp.float32))
+    gates, eidx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    gates = gates / gates.sum(-1, keepdims=True)
+    mine = (eidx >= held.start) & (eidx < held.stop)
+    if valid is not None:
+        mine = mine & valid.reshape(t, 1)
+    # pairs of held experts first, by expert; the rest share a last key
+    key = jnp.where(mine, eidx - held.start, len(held)).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    load = jnp.sum(key[:, None] == jnp.arange(len(held)), axis=0,
+                   dtype=jnp.int32)
+    # one named scope over the expert computation, in its operations' HLO
+    # op_name metadata; the v5e's device trace carries no metadata, and
+    # there the grouped matmuls are XLA's kernel ``%ragged-dot-none``
+    with jax.named_scope("moe_experts"):
+        tok = order // k
+        xs = x[tok]
+        g = jax.lax.ragged_dot(xs, params["w_gate"], load)
+        u = jax.lax.ragged_dot(xs, params["w_up"], load)
+        out = jax.lax.ragged_dot(_act(g, u, cfg.mlp_act), params["w_down"],
+                                 load)
+        keep = key[order] < len(held)
+        w = jnp.where(keep, gates.reshape(-1)[order], 0.0)
+        # rows past the held pairs are not computed: select, not multiply
+        contrib = jnp.where(keep[:, None],
+                            out.astype(jnp.float32) * w[:, None], 0.0)
+        y = jnp.zeros((t, d), jnp.float32).at[tok].add(contrib)
+    y = y.astype(h.dtype)
+    if "shared" in params:
+        sp = params["shared"]
+        y = y + jnp.einsum("tf,fd->td", _act(
+            jnp.einsum("td,df->tf", x, sp["w_gate"]),
+            jnp.einsum("td,df->tf", x, sp["w_up"]), cfg.mlp_act),
+            sp["w_down"])
+    return y.reshape(shape), load

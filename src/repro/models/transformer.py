@@ -62,8 +62,13 @@ def layer_flags(cfg: ModelConfig) -> jax.Array:
     return jnp.ones((cfg.num_layers,), bool)
 
 
-def _mlp_or_moe(layer_params, cfg, h, ctx):
+def _mlp_or_moe(layer_params, cfg, h, ctx, dropless=False, valid=None):
+    """(output, aux): the load-balance loss of training's capacity layer,
+    or with ``dropless`` (serving) the layer's load on each held expert
+    (``moe.moe_dropless``) over the tokens ``valid`` marks."""
     if cfg.family == "moe":
+        if dropless:
+            return moe_mod.moe_dropless(layer_params["moe"], h, cfg, valid)
         return moe_mod.moe_mlp(layer_params["moe"], h, cfg, ctx)
     return mlp(layer_params["mlp"], h, cfg.mlp_act, ctx), 0.0
 
@@ -83,7 +88,8 @@ def _layer_window(cfg: ModelConfig, is_global):
 
 def _block_fwd(layer_params, x, cfg: ModelConfig, *, is_global, cos_l, sin_l,
                cos_g, sin_g, prefix_len, q_offset, kv_override=None,
-               causal=True, prefill_tiles=None, ctx: ShardCtx):
+               causal=True, prefill_tiles=None, dropless=False,
+               ctx: ShardCtx):
     cos = jnp.where(is_global, cos_g, cos_l) if cfg.local_global_ratio else cos_g
     sin = jnp.where(is_global, sin_g, sin_l) if cfg.local_global_ratio else sin_g
     h = rmsnorm(x, layer_params["ln1"], cfg.norm_eps)
@@ -94,9 +100,9 @@ def _block_fwd(layer_params, x, cfg: ModelConfig, *, is_global, cos_l, sin_l,
         prefill_tiles=prefill_tiles, ctx=ctx)
     x = ctx.p(x + a, "batch", "seq_sp", "embed")
     h = rmsnorm(x, layer_params["ln2"], cfg.norm_eps)
-    m, aux = _mlp_or_moe(layer_params, cfg, h, ctx)
+    m, aux = _mlp_or_moe(layer_params, cfg, h, ctx, dropless)
     x = ctx.p(x + m, "batch", "seq_sp", "embed")
-    return x, kv, aux
+    return x, kv, (0.0 if dropless else aux)
 
 
 def forward(
@@ -108,6 +114,7 @@ def forward(
     remat: str = "none",                  # none | full | dots
     return_cache: bool = False,
     prefill_tiles: Optional[tuple[int, int]] = None,
+    dropless: bool = False,
     ctx: ShardCtx = NO_SHARD,
 ):
     """Training/prefill forward.  Returns (logits, aux_loss[, kv caches]).
@@ -115,7 +122,8 @@ def forward(
     ``prefill_tiles`` — the serving router's bucket-tuned flash
     (block_q, block_k) — makes every layer's attention EXECUTE at that
     mapping (see ``attention.attention_block``); ``None`` keeps the
-    GSPMD path."""
+    GSPMD path.  ``dropless`` (serving's prefill) runs the expert layers
+    without capacity (``moe.moe_dropless``); the aux loss is then 0."""
     if (ctx.flag("banded_local", False) and cfg.local_global_ratio
             and cfg.window and prefix_embeds is None):
         return forward_banded(params, tokens, cfg, remat=remat,
@@ -140,7 +148,7 @@ def forward(
                               cos_l=cos_l, sin_l=sin_l, cos_g=cos_g,
                               sin_g=sin_g, prefix_len=prefix_len,
                               q_offset=0, prefill_tiles=prefill_tiles,
-                              ctx=ctx)
+                              dropless=dropless, ctx=ctx)
         return (x, aux + a), (kv if return_cache else None)
 
     if remat == "full":
@@ -282,6 +290,7 @@ def chunk_prefill_step(
     tokens: jax.Array,                    # (B, C) — one prompt chunk
     cfg: ModelConfig,
     *,
+    n_valid=None,
     prefill_tiles: Optional[tuple[int, int]] = None,
     ctx: ShardCtx = NO_SHARD,
 ):
@@ -304,8 +313,12 @@ def chunk_prefill_step(
     engine's ``write_row`` then copies only real positions into the
     pool.  No validity mask is needed inside the step.
 
-    Returns (logits (B, C, V), updated cache).  The caller reads the
-    true last-token logits at index ``n_valid - 1`` of the final chunk.
+    Returns (logits (B, C, V), updated cache), and for a config with
+    experts also the dropless layer's load (L, held) int32: its routed
+    pairs on each held expert, of the chunk's first ``n_valid`` (traced;
+    default all) positions — padding adds no expert work.  The caller
+    reads the true last-token logits at index ``n_valid - 1`` of the
+    final chunk.
     """
     b, c = tokens.shape
     x = embed(params["embed"], tokens)
@@ -319,6 +332,10 @@ def chunk_prefill_step(
     # the untiled reference schedule (serving always passes tuned tiles)
     bq, bk = prefill_tiles if prefill_tiles is not None else (c, 512)
     use_pallas, interpret = _pallas_mode()
+    experts = cfg.family == "moe"
+    valid = None
+    if experts and n_valid is not None:
+        valid = jnp.broadcast_to(jnp.arange(c) < n_valid, (b, c))
 
     def body(x, xs):
         layer_params, is_global, k_c, v_c = jax.lax.optimization_barrier(xs)
@@ -346,14 +363,17 @@ def chunk_prefill_step(
                        layer_params["attn"]["wo"])
         x = x + a
         h = rmsnorm(x, layer_params["ln2"], cfg.norm_eps)
-        m, _ = _mlp_or_moe(layer_params, cfg, h, ctx)
-        return x + m, (k_c, v_c)
+        m, load = _mlp_or_moe(layer_params, cfg, h, ctx, True, valid)
+        return x + m, ((k_c, v_c, load) if experts else (k_c, v_c))
 
-    x, (k_new, v_new) = jax.lax.scan(
+    x, ys = jax.lax.scan(
         body, x, (params["blocks"], flags, cache["k"], cache["v"]))
     x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
     logits = unembed(params["embed"], x, ctx)
-    return logits, {"k": k_new, "v": v_new, "pos": start + c}
+    new_cache = {"k": ys[0], "v": ys[1], "pos": start + c}
+    if experts:
+        return logits, new_cache, ys[2]
+    return logits, new_cache
 
 
 def decode_step(
@@ -367,8 +387,13 @@ def decode_step(
     page_tables=None,
     page_block: Optional[int] = None,
     paged_decode_block: Optional[int] = None,
+    rows=None,
 ):
-    """One greedy decode step: (logits (B,1,V), updated cache).
+    """One greedy decode step: (logits (B,1,V), updated cache), and for a
+    config with experts also the dropless layer's load (L, held) int32:
+    its routed pairs on each held expert, of the rows ``rows`` (B,) bool
+    marks in flight (default all) — a row not in flight adds no expert
+    work.
 
     ``cache["pos"]`` may be a scalar (every row at the same depth — the
     fixed-batch loop) or a (B,) vector (the serving pool's ragged rows:
@@ -393,6 +418,7 @@ def decode_step(
     # the layer scan; the branch is PYTHON-level (a dict-key check), so
     # the unquantized trace stays byte-identical to the pre-int8 graph
     quant = "k_scale" in cache
+    experts = cfg.family == "moe"
 
     def body(x, xs):
         if quant:
@@ -414,16 +440,19 @@ def decode_step(
             k_scale=ks, v_scale=vs, ctx=ctx)
         x = x + a
         h = rmsnorm(x, layer_params["ln2"], cfg.norm_eps)
-        m, _ = _mlp_or_moe(layer_params, cfg, h, ctx)
-        return x + m, kv
+        m, load = _mlp_or_moe(layer_params, cfg, h, ctx, True, rows)
+        return x + m, ((kv, load) if experts else kv)
 
     xs = (params["blocks"], flags, cache["k"], cache["v"])
     if quant:
         xs = xs + (cache["k_scale"], cache["v_scale"])
-    x, kv_new = jax.lax.scan(body, x, xs)
+    x, ys = jax.lax.scan(body, x, xs)
+    kv_new = ys[0] if experts else ys
     x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
     logits = unembed(params["embed"], x, ctx)
     new_cache = {"k": kv_new[0], "v": kv_new[1], "pos": pos + 1}
     if quant:
         new_cache["k_scale"], new_cache["v_scale"] = kv_new[2], kv_new[3]
+    if experts:
+        return logits, new_cache, ys[1]
     return logits, new_cache

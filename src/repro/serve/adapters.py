@@ -40,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Optional, Protocol
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["CacheAdapter", "FamilyCacheAdapter", "ADAPTERS", "get_adapter"]
@@ -184,11 +185,15 @@ class FamilyCacheAdapter:
         the mask/rope boundary, regardless of padding.
 
         ``page_map`` (prompt_len - start,) — flat physical positions
-        from the request's block table — switches the length-bearing
-        keys to the PAGED write: only the prompt's own tokens scatter
-        into the leased blocks (no full-row copy, no tail padding;
-        positions past the prompt are masked by ``pos`` until decode
-        overwrites them).
+        from the request's block table — with ``page_block`` switches
+        the length-bearing keys to the PAGED write: only the prompt's
+        own tokens land in the leased blocks (no full-row copy, no tail
+        padding; positions past the prompt are masked by ``pos`` until
+        decode overwrites them).
+
+        Every write is a ``dynamic_update_slice`` (one per block on the
+        paged path), so under ``jit`` with the pool donated the row
+        lands in place, in whatever layout the pool has.
 
         ``start`` (block-aligned, paged-only) begins the write
         mid-prompt: positions ``[0, start)`` live in radix-SHARED blocks
@@ -218,24 +223,24 @@ class FamilyCacheAdapter:
                 continue
             row = arr[:, 0]                        # (L, ...) single row
             if key in self.length_keys and page_map is not None:
-                n, b, t = out[key].shape[0], out[key].shape[1], kv_len
+                assert page_block is not None, "a paged write needs page_block"
                 vals = row[:, start:prompt_len]
                 if key + "_scale" in out:
-                    assert scale_map is not None and page_block is not None
+                    assert scale_map is not None
                     vals, out = self._quantize_prompt(
                         out, key, vals, start, prompt_len, kv_len,
                         scale_map, int(page_block))
                 if prompt_len > start:
-                    flat = out[key].reshape((n, b * t) + out[key].shape[3:])
-                    flat = flat.at[:, page_map].set(vals)
-                    out[key] = flat.reshape(out[key].shape)
+                    out[key] = _write_pages(out[key], page_map, vals,
+                                            kv_len, int(page_block))
                 continue
             if key in self.length_keys:
                 pad = kv_len - row.shape[1]
                 assert pad >= 0, "prompt bucket outgrew the pool row"
                 widths = ((0, 0), (0, pad)) + ((0, 0),) * (row.ndim - 2)
                 row = jnp.pad(row, widths)
-            out[key] = out[key].at[:, slot].set(row)
+            out[key] = jax.lax.dynamic_update_slice_in_dim(
+                out[key], row[:, None].astype(out[key].dtype), slot, axis=1)
         out["pos"] = out["pos"].at[slot].set(prompt_len)
         return out
 
@@ -300,6 +305,26 @@ class FamilyCacheAdapter:
                 out[skey] = jnp.pad(out[skey],
                                     ((0, 0), (0, 0), (0, pad_nb), (0, 0)))
         return out
+
+
+def _write_pages(arr, page_map, vals, kv_len: int, bs: int):
+    """``arr`` (L, B, T, ...) with ``vals`` (L, n, ...) at the flat
+    (B*T) positions ``page_map`` (n,), which start on a block boundary.
+    A block's tokens are contiguous in one row (``flat_position``), so
+    each block is one ``dynamic_update_slice`` at its first token."""
+    full, rest = divmod(vals.shape[1], bs)
+    first = page_map[::bs]
+    rows, offs = first // kv_len, first % kv_len
+    tail = (0,) * (arr.ndim - 3)
+
+    def put(a, i, size):
+        blk = jax.lax.dynamic_slice_in_dim(vals, i * bs, size, axis=1)
+        return jax.lax.dynamic_update_slice(
+            a, blk[:, None].astype(a.dtype), (0, rows[i], offs[i]) + tail)
+
+    if full:
+        arr = jax.lax.fori_loop(0, full, lambda i, a: put(a, i, bs), arr)
+    return put(arr, full, rest) if rest else arr
 
 
 def _encdec_frames(model, rows: int) -> dict:

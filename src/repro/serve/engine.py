@@ -38,6 +38,7 @@ device-free benchmarks).
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 from typing import Any, Callable, Optional
 
@@ -65,6 +66,20 @@ from repro.serve.scheduler import Request, Scheduler
 from repro.tuner import TuningCache
 
 __all__ = ["ServeEngine", "ServeReport"]
+
+#: the most (token, expert) pairs one "auto" prefill chunk of a model
+#: with experts routes: 256 tokens at top-8
+CHUNK_PAIRS = 2048
+
+
+def _expert_load_attrs(load) -> dict:
+    """Span attributes of one step's expert load (L, held): routed pairs
+    on held experts summed over layers, held experts with at least one
+    pair summed over layers, and the most pairs on one held expert."""
+    load = np.asarray(load)
+    return {"moe_routed": int(load.sum()),
+            "moe_touched": int((load > 0).sum()),
+            "moe_max_load": int(load.max(initial=0))}
 
 
 @dataclasses.dataclass
@@ -303,6 +318,12 @@ class ServeEngine:
                                static_argnames=("decode_block",
                                                 "page_block",
                                                 "paged_decode_block"))
+        # one program per write shape, the pool donated: the row lands in
+        # place and the pool is never held twice
+        self._write_row = jax.jit(self.adapter.write_row,
+                                  static_argnames=("prompt_len", "kv_len",
+                                                   "page_block", "start"),
+                                  donate_argnames=("cache",))
         self._chunk_step = jax.jit(
             make_chunk_prefill_step(self.model, self.plan),
             static_argnames=("prefill_tiles",))
@@ -325,6 +346,12 @@ class ServeEngine:
         self._tables_dev = None      # device-array memo (tables are data
         #                              but change only at admit/retire)
         self._tokens = np.zeros((slots, 1), np.int32)
+        #: moe: the steps also return the expert layer's load, and the
+        #: decode step is told which rows are in flight (row written,
+        #: not yet retired) so that idle rows add no expert work
+        self._experts = cfg.family == "moe"
+        self._in_flight = np.zeros(slots, bool)
+        self._in_flight_dev = None
         self._plan_len = -1                  # _current_plan memo key
         self._bucket_plan = None
         self.compiled_decode_shapes: set[tuple[int, int]] = set()
@@ -389,11 +416,20 @@ class ServeEngine:
                                -1, np.int32)
         self._tables_dev = None
         self._tokens = np.zeros((self.slots, 1), np.int32)
+        self._in_flight = np.zeros(self.slots, bool)
+        self._in_flight_dev = None
         self.pool_growths = 0
         self._t0 = None
         self._skew = 0.0
         self._chunk_tasks = []
         self._prefilling = {}
+        # what is live now (jitted steps, plans, weights, the tuning
+        # cache) lives as long as the engine: move it out of the cyclic
+        # collector's reach, so that a full collection while serving
+        # walks only what serving allocated since; one over the whole
+        # warm heap takes tens of ms, all inside one engine step.
+        gc.collect()
+        gc.freeze()
 
     # -- time -------------------------------------------------------------
 
@@ -527,11 +563,11 @@ class ServeEngine:
                 pm = self._page_map(blocks, off + plen)
                 if self.kv_spec.quantized:
                     sm = self._scale_map(blocks)
-            self._cache = self.adapter.write_row(self._cache, req.slot,
-                                                 rcache, off + plen,
-                                                 self.pool.kv_len,
-                                                 page_map=pm, scale_map=sm,
-                                                 page_block=self._block_size)
+            self._cache = self._write_row(self._cache, req.slot, rcache,
+                                          off + plen, self.pool.kv_len,
+                                          page_map=pm, scale_map=sm,
+                                          page_block=self._block_size)
+            self._set_in_flight(req.slot, True)
         with self.obs.span("sample", rows=1):
             first = int(jnp.argmax(logits[0, -1]))
             req.generated.append(first)
@@ -548,7 +584,17 @@ class ServeEngine:
         # "auto": the tuned tile's block_q — the quantum the tuner
         # already decided a prefill sweep should advance in (32 for
         # attention-free families, which have no tile decision)
-        return int(tiles[0]) if tiles else 32
+        width = int(tiles[0]) if tiles else 32
+        if self._experts:
+            # a chunk's step lands whole inside one gap of every decoding
+            # row, and the dropless layer carries all chunk x top-k pairs
+            # of it, held or not (gathered, weighted and scattered back
+            # in float32), so its cost grows top-k times as fast per token
+            # as a dense layer's.  Capping the pairs keeps every chunk's
+            # stall about one size, whatever the prompt's bucket.
+            cap = CHUNK_PAIRS // max(self.cfg.moe_topk, 1)
+            width = min(width, 1 << (cap.bit_length() - 1))
+        return width
 
     def _admit_chunked(self, req: Request, now: float) -> None:
         """Seat the request (slot + blocks leased, capacity held) but
@@ -674,14 +720,18 @@ class ServeEngine:
         self.compiled_chunk_shapes.add((c, cache_len, task.tiles))
         with self.obs.span("prefill_chunk", rid=task.req.rid,
                            bucket=task.pb, chunk=c, start=start,
-                           tiles=task.tiles):
+                           tiles=task.tiles) as sp:
             t0 = time.perf_counter()
-            logits, task.cache = self._chunk_step(
+            out = self._chunk_step(
                 self.params, task.cache, jnp.asarray(buf), jnp.int32(n),
                 prefill_tiles=task.tiles)
+            logits, task.cache = out[:2]
+            load = self._start_load_readback(out)
             with self.obs.span("wait"):
                 logits = jax.block_until_ready(logits)
             self.metrics.add_prefill_time(time.perf_counter() - t0)
+            if load is not None:
+                sp.set(**_expert_load_attrs(load))
         task.done += n
         if task.done >= len(task.toks):
             self._finish_chunked(task, logits, n)
@@ -707,12 +757,13 @@ class ServeEngine:
                 assert self.pool.refcount(
                     task.blocks[req.prompt_len // self._block_size]) == 1, \
                     "decode-append block is shared"
-            self._cache = self.adapter.write_row(self._cache, req.slot,
-                                                 task.cache, req.prompt_len,
-                                                 self.pool.kv_len,
-                                                 page_map=pm, scale_map=sm,
-                                                 page_block=self._block_size,
-                                                 start=task.start)
+            self._cache = self._write_row(self._cache, req.slot,
+                                          task.cache, req.prompt_len,
+                                          self.pool.kv_len, page_map=pm,
+                                          scale_map=sm,
+                                          page_block=self._block_size,
+                                          start=task.start)
+            self._set_in_flight(req.slot, True)
         if self._radix is not None:
             # index the request's fully-written prompt blocks (shared
             # prefix nodes are reused; only new nodes retain); the
@@ -732,6 +783,20 @@ class ServeEngine:
 
     # -- decode -----------------------------------------------------------
 
+    def _set_in_flight(self, slot: int, on: bool) -> None:
+        if self._in_flight[slot] != on:
+            self._in_flight[slot] = on
+            self._in_flight_dev = None
+
+    def _start_load_readback(self, out):
+        """A step's expert load, its copy to the host started, while
+        tracing (the span attributes read it after the step's wait);
+        None for dense steps or with tracing off."""
+        if len(out) < 3 or not self.obs.enabled:
+            return None
+        out[2].copy_to_host_async()
+        return out[2]
+
     def _decode_tick(self) -> None:
         self.compiled_decode_shapes.add(self._decode_shape())
         # the bucket's resolved plan, whose decode_block parameterizes
@@ -750,6 +815,10 @@ class ServeEngine:
                       # read back to gather-then-sweep (the ablation)
                       paged_decode_block=(plan.paged_decode_block
                                           if self.fused_decode else None))
+        if self._experts:
+            if self._in_flight_dev is None:
+                self._in_flight_dev = jnp.asarray(self._in_flight)
+            kw["rows"] = self._in_flight_dev
         # the summed context of the rows this tick decodes (each reads
         # its prompt and every token it has generated), and on the paged
         # pool the pages that covers: what the fused read fetches a layer
@@ -768,17 +837,19 @@ class ServeEngine:
                            paged_decode_block=kw.get("paged_decode_block"),
                            live=len(self.scheduler.live), slots=self.slots,
                            ctx_tokens=ctx, pages=pages,
-                           pool_len=self.pool.kv_len):
+                           pool_len=self.pool.kv_len) as sp:
             t0 = time.perf_counter()
-            logits, self._cache = self._decode(self.params,
-                                               dict(self._cache),
-                                               jnp.asarray(self._tokens),
-                                               decode_block=plan.decode_block,
-                                               **kw)
+            out = self._decode(self.params, dict(self._cache),
+                               jnp.asarray(self._tokens),
+                               decode_block=plan.decode_block, **kw)
+            logits, self._cache = out[:2]
+            load = self._start_load_readback(out)
             with self.obs.span("wait"):
                 logits = jax.block_until_ready(logits)
             dt = time.perf_counter() - t0
             self.metrics.add_decode_time(dt)
+            if load is not None:
+                sp.set(**_expert_load_attrs(load))
         if self.retune is not None:
             # the tick's EXECUTED mapping (mirrors the span attribution):
             # the fused block_s when the paged read ran fused, the dense
@@ -826,6 +897,8 @@ class ServeEngine:
                 if self.paged and slot is not None:
                     self._tables[slot] = -1      # unmap: blocks recycle
                     self._tables_dev = None
+                if slot is not None:
+                    self._set_in_flight(slot, False)
                 self.obs.instant("slot_recycle", rid=req.rid, slot=slot,
                                  generated=len(req.generated))
                 self.outputs[req.rid] = list(req.prompt) + list(req.generated)

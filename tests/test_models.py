@@ -64,7 +64,8 @@ def test_smoke_decode_step(arch):
     params = m.init(jax.random.key(0))
     cache = m.init_cache(batch=2, max_len=16)
     tok = jnp.ones((2, 1), jnp.int32)
-    logits, cache2 = m.decode_step(params, cache, tok)
+    # (moe also returns the expert layer's load)
+    logits, cache2 = m.decode_step(params, cache, tok)[:2]
     lg = logits[:, 0] if logits.ndim == 3 else logits
     assert lg.shape == (2, cfg.vocab_size)
     assert bool(jnp.isfinite(lg).all())
@@ -90,7 +91,7 @@ def test_prefill_decode_matches_forward(arch):
     full = m.forward(params, batch)[0]
     pbatch = dict(batch, tokens=toks[:, :-1])
     _, cache = m.prefill(params, pbatch, max_len=32)
-    lg, _ = m.decode_step(params, cache, toks[:, -1:])
+    lg = m.decode_step(params, cache, toks[:, -1:])[0]
     got = lg[:, 0] if lg.ndim == 3 else lg
     atol = 0.2 if cfg.family == "moe" else 1e-3
     np.testing.assert_allclose(np.asarray(got), np.asarray(full[:, -1]),

@@ -1,5 +1,6 @@
 """MoE layer: routing exactness, capacity drops, group-locality, EP
-shardability of the dispatch."""
+shardability of the dispatch; the dropless layer serving runs, whole and
+as one chip's share of the experts."""
 
 import dataclasses
 
@@ -10,8 +11,9 @@ import pytest
 
 import repro.models.moe as moe_mod
 from repro.configs import get_config
+from repro.configs.base import ExpertShare
 from repro.models.layers import NO_SHARD, ShardCtx, init_params
-from repro.models.moe import moe_mlp, moe_specs
+from repro.models.moe import moe_dropless, moe_mlp, moe_specs
 
 
 def make(name="deepseek-moe-16b"):
@@ -96,3 +98,89 @@ def test_qwen3_moe_reduced_smoke():
     h = jax.random.normal(jax.random.key(1), (2, 16, cfg.d_model)) * 0.5
     out, aux = moe_mlp(params, h, cfg, NO_SHARD)
     assert out.shape == h.shape and bool(jnp.isfinite(out).all())
+
+
+# --------------------------------------------------------------------------- #
+# The dropless layer serving runs
+# --------------------------------------------------------------------------- #
+
+
+def held_params(params, first, held):
+    """The weights a share holds: the router whole, its experts' slice."""
+    return dict(params, **{k: params[k][first:first + held]
+                           for k in ("w_gate", "w_up", "w_down")})
+
+
+@pytest.mark.parametrize("name", ["qwen3-moe-235b-a22b", "deepseek-moe-16b"])
+def test_dropless_equals_uncut_reference(name):
+    cfg, params = make(name)
+    h = jax.random.normal(jax.random.key(1), (2, 16, cfg.d_model)) * 0.5
+    out, load = moe_dropless(params, h, cfg)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(dense_reference(params, h, cfg)),
+                               rtol=1e-5, atol=1e-5)
+    assert load.shape == (cfg.moe_experts,)
+    assert int(load.sum()) == 2 * 16 * cfg.moe_topk
+
+
+def test_dropless_one_expert_takes_the_whole_batch():
+    """Every token routed to expert 5: no pair is dropped, where the
+    capacity layer must drop most of them."""
+    cfg, params = make("qwen3-moe-235b-a22b")
+    params = dict(params, router=jnp.zeros_like(params["router"])
+                  .at[:, 5].set(1.0))
+    h = jnp.abs(jax.random.normal(jax.random.key(2),
+                                  (2, 16, cfg.d_model))) + 0.1
+    want = dense_reference(params, h, cfg)
+    out, load = moe_dropless(params, h, cfg)
+    assert int(load[5]) == 32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    capped, _ = moe_mlp(params, h, cfg, NO_SHARD)
+    assert not np.allclose(np.asarray(capped), np.asarray(want), atol=1e-3)
+
+
+def test_expert_shares_add_up_to_the_uncut_layer():
+    """4 shares of a 16-expert top-4 layer, each computing only its own
+    experts' part, add up to the uncut layer; every pair lands on
+    exactly one share."""
+    cfg = dataclasses.replace(make()[0], moe_experts=16, moe_topk=4,
+                              moe_shared_experts=0)
+    params = init_params(moe_specs(cfg), jax.random.key(3), jnp.float32)
+    h = jax.random.normal(jax.random.key(4), (3, 8, cfg.d_model)) * 0.5
+    total, pairs = 0.0, 0
+    for rank in range(4):
+        part = ExpertShare.of(cfg, 4 * rank, 4)
+        specs = moe_specs(part)
+        assert specs["router"].shape == (cfg.d_model, 16)
+        assert specs["w_gate"].shape == (4, cfg.d_model, cfg.moe_dff)
+        out, load = moe_dropless(held_params(params, 4 * rank, 4), h, part)
+        total, pairs = total + out, pairs + int(load.sum())
+    np.testing.assert_allclose(np.asarray(total),
+                               np.asarray(dense_reference(params, h, cfg)),
+                               rtol=1e-5, atol=1e-5)
+    assert pairs == 3 * 8 * 4
+
+
+def test_rows_not_in_flight_add_no_expert_work():
+    cfg, params = make("qwen3-moe-235b-a22b")
+    h = jax.random.normal(jax.random.key(5), (6, 1, cfg.d_model)) * 0.5
+    rows = jnp.asarray([True, False, True, False, False, True])
+    full, full_load = moe_dropless(params, h, cfg)
+    out, load = moe_dropless(params, h, cfg, rows)
+    np.testing.assert_array_equal(np.asarray(out[~rows]), 0.0)
+    np.testing.assert_allclose(np.asarray(out[rows]),
+                               np.asarray(full[rows]), rtol=1e-6, atol=1e-6)
+    _, live_load = moe_dropless(params, h[rows], cfg)
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(live_load))
+    assert int(full_load.sum()) == 6 * cfg.moe_topk
+
+
+def test_a_share_must_lie_inside_the_router():
+    cfg = make()[0]
+    with pytest.raises(ValueError, match="not a share"):
+        ExpertShare.of(cfg, 6, 4)
+    with pytest.raises(ValueError, match="every expert held"):
+        moe_mlp(held_params(make()[1], 0, 4),
+                jnp.zeros((1, 2, cfg.d_model)), ExpertShare.of(cfg, 0, 4),
+                NO_SHARD)

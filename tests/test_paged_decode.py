@@ -313,7 +313,7 @@ def _sequential_reference(cfg, params, prompts, max_new):
         out = [int(jnp.argmax(logits[0, -1]))]
         for _ in range(max_new - 1):
             logits, cache = decode(params, cache,
-                                   jnp.asarray([[out[-1]]], jnp.int32))
+                                   jnp.asarray([[out[-1]]], jnp.int32))[:2]
             lg = logits[:, 0] if logits.ndim == 3 else logits
             out.append(int(jnp.argmax(lg[0])))
         outs.append(out)
@@ -567,6 +567,49 @@ if HAVE_HYPOTHESIS:
     @given(seed=st.integers(0, 1 << 30))
     def test_scales_never_alias_across_recycles(seed):
         _check_scales_never_alias_across_recycles(seed)
+
+
+@pytest.mark.parametrize("plen,start", [(1, 0), (16, 0), (37, 0),
+                                        (37, 16), (48, 32)])
+def test_paged_row_write_lands_the_prompt_in_place(plen, start):
+    """``write_row``'s paged write, jitted with the pool donated as the
+    engine runs it: exactly the prompt's tokens ``[start, plen)`` land
+    at their blocks' flat positions, every other pool position keeps
+    its value, and the output takes the donated pool's buffer."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.paged_gather import flat_position
+    from repro.serve import get_adapter
+
+    rng = np.random.default_rng(plen * 100 + start)
+    n_l, slots, kv_len, bs, g, hd = 2, 3, 64, 16, 2, 4
+    adapter = get_adapter("dense")
+    pool = rng.standard_normal((n_l, slots, kv_len, g, hd)).astype(np.float32)
+    row = rng.standard_normal((n_l, 1, 64, g, hd)).astype(np.float32)
+    blocks = rng.permutation(slots * kv_len // bs)[:-(-plen // bs)]
+    tok = np.arange(start, plen)
+    flat = flat_position(blocks[tok // bs], tok, slots, kv_len, bs)
+    # made by a device op, so that the pool's buffers are XLA's own
+    cache = {"k": jnp.asarray(pool) + 0.0, "v": jnp.asarray(pool) + 0.0,
+             "pos": jnp.zeros((slots,), jnp.int32)}
+    donated = cache["k"]
+    buf = donated.unsafe_buffer_pointer()
+    write = jax.jit(adapter.write_row,
+                    static_argnames=("prompt_len", "kv_len", "page_block",
+                                     "start"),
+                    donate_argnames=("cache",))
+    out = write(cache, 1, {"k": jnp.asarray(row), "v": jnp.asarray(row)},
+                plen, kv_len, page_map=jnp.asarray(flat, jnp.int32),
+                page_block=bs, start=start)
+    want = pool.reshape(n_l, slots * kv_len, g, hd).copy()
+    want[:, flat] = row[:, 0, start:plen]
+    for key in ("k", "v"):
+        np.testing.assert_array_equal(
+            np.asarray(out[key]).reshape(want.shape), want)
+    assert int(out["pos"][1]) == plen
+    assert donated.is_deleted()
+    assert out["k"].unsafe_buffer_pointer() == buf
 
 
 def test_table_invariants_seeded_sweep():

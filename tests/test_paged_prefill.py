@@ -54,7 +54,7 @@ def _sequential_reference(cfg, params, prompts, max_new):
         out = [int(jnp.argmax(logits[0, -1]))]
         for _ in range(max_new - 1):
             logits, cache = decode(params, cache,
-                                   jnp.asarray([[out[-1]]], jnp.int32))
+                                   jnp.asarray([[out[-1]]], jnp.int32))[:2]
             lg = logits[:, 0] if logits.ndim == 3 else logits
             out.append(int(jnp.argmax(lg[0])))
         outs.append(out)

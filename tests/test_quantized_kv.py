@@ -310,9 +310,9 @@ def _drive_with_logits(cfg, params, kv_dtype):
     real = eng._decode
 
     def spy(*a, **kw):
-        lg, cache = real(*a, **kw)
-        log.append(np.asarray(lg))
-        return lg, cache
+        out = real(*a, **kw)       # moe steps also return the expert load
+        log.append(np.asarray(out[0]))
+        return out
 
     eng._decode = spy
     reqs = [eng.submit(p, max_new_tokens=_MAX_NEW) for p in _PROMPTS]

@@ -332,6 +332,36 @@ def test_engine_matches_sequential_decode(f32_cfg):
     assert report.router_stats["probes"] > 0          # cold buckets refined
 
 
+def test_reset_freezes_the_warm_heap(f32_cfg):
+    """``reset`` moves what is live (the warm machinery) out of the
+    cyclic collector's reach, and an engine served again after it gives
+    the same tokens."""
+    import gc
+
+    import jax
+
+    from repro.models import build_model
+    from repro.serve import ServeEngine
+
+    params = build_model(f32_cfg).init(jax.random.key(0))
+    eng = ServeEngine(f32_cfg, slots=2, max_len=64, params=params,
+                      tuning_cache=TuningCache(path=None))
+    prompts = [[7, 3, 99], [11, 5, 2, 42, 17]]
+    outs = []
+    try:
+        for _ in range(2):
+            reqs = [eng.submit(p, max_new_tokens=3) for p in prompts]
+            report = eng.run()
+            outs.append([report.outputs[r.rid] for r in reqs])
+            frozen = gc.get_freeze_count()
+            eng.reset()
+            assert gc.get_freeze_count() > frozen
+            assert not eng.outputs and not eng.scheduler.live
+    finally:
+        gc.unfreeze()
+    assert outs[0] == outs[1]
+
+
 @pytest.mark.parametrize("arch", ["mamba2-1.3b", "zamba2-7b",
                                   "whisper-medium"])
 def test_engine_matches_sequential_decode_families(arch):

@@ -1,5 +1,6 @@
 """Ahead-of-time compiles of the serving path's Pallas kernels for a
-described TPU v5e chip (no chip attached), at smollm-135m widths.
+described TPU v5e chip (no chip attached), at smollm-135m widths, and of
+the dropless expert layer at Qwen3-235B-A22B widths.
 
 Interpret-mode tests cannot see what the TPU compiler refuses: a block
 shape that breaks the (8, 128) tiling rule, or more VMEM than a kernel
@@ -172,3 +173,27 @@ def test_matmul_auto_compiles_at_4096(one_chip):
     _assert_kernel_compiles(
         lambda x, y: matmul_pallas(x, y, hw=V5E, policy=MappingPolicy.AUTO),
         a, a)
+
+
+@pytest.mark.parametrize("tokens", [64, 1024], ids=["decode-64", "chunk-1024"])
+def test_dropless_expert_layer_compiles_at_qwen3_moe_widths(one_chip, tokens):
+    """The serving expert layer of the Qwen3-235B-A22B cell (d 4096, a
+    router of 128, top 8, experts 0-7 held, width 1536) over the decode
+    step's 64 rows and a 1024-token prefill chunk: its grouped matmuls
+    run as XLA's TPU kernel (``%ragged-dot-none``), not a dense loop."""
+    from repro.configs import get_config
+    from repro.configs.base import ExpertShare
+    from repro.models.moe import moe_dropless
+
+    cfg = ExpertShare.of(get_config("qwen3-moe-235b-a22b"), 0, 8)
+    d, ff = cfg.d_model, cfg.moe_dff
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = {"router": s((d, 128)), "w_gate": s((8, d, ff)),
+              "w_up": s((8, d, ff)), "w_down": s((8, ff, d))}
+    text = jax.jit(lambda p, h, v: moe_dropless(p, h, cfg, v)).lower(
+        params, s((tokens, 1, d)), s((tokens, 1), jnp.bool_)
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "%ragged-dot-none" in text
